@@ -8,6 +8,28 @@ import time
 import numpy as np
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "results")
+# JAX's persistent compilation cache when JAX_COMPILATION_CACHE_DIR is
+# unset: a fixed place in the checkout, since the directory is part of
+# what the cache is keyed on and a moving one never hits
+COMPILE_CACHE_DIR = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", ".jax_cache"))
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    Called by the scripts that drive a chip (``chip_smoke.py`` and the
+    benchmark entry points) before they compile anything, never on
+    import.  ``JAX_COMPILATION_CACHE_DIR``, when set, is where JAX keeps
+    the cache and nothing is set here; otherwise the cache goes to
+    ``<repo>/.jax_cache``.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 def best_of(k: int, fn, *, warmup: int = 0) -> tuple[float, object]:

@@ -40,4 +40,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from benchmarks.common import use_compile_cache
+    use_compile_cache()
     raise SystemExit(main())

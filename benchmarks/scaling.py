@@ -43,14 +43,17 @@ Three device-resident-solve columns ride along (PR 8):
                                 ``L * budget`` (measured once at a
                                 fixed size; < 1 is the win).
 
-A ``sharded_fused`` scale-out section rides along (PR 10): the fused
-kernel inside shard_map shards over a two-level hierarchical partition,
-measured on multiple virtual CPU devices in a subprocess
-(``--xla_force_host_platform_device_count``) at sizes up to 1M nodes /
-10M edges — far beyond the in-process ladder.  Each row reports
-per-shard and aggregate edge-iters/s against two same-process
-references: the single-device fused path and the single-shard (S=1)
-hierarchical solve, both at the matched per-shard size.
+A ``sharded_fused`` scale-out section rides along: the fused kernel
+inside shard_map shards over a two-level hierarchical partition, at
+sizes up to 1M nodes / 10M edges — far beyond the in-process ladder.
+On a TPU host it runs in this process over every chip (a child could
+not reach chips this process holds); off-TPU it runs on virtual CPU
+devices in a subprocess (``--xla_force_host_platform_device_count``).
+Each row reports per-shard and aggregate edge-iters/s against two
+same-process references: the single-device fused path and the
+single-shard (S=1) hierarchical solve, both at the matched per-shard
+size.  A row whose per-shard fused window exceeds the cap (on a TPU,
+the VMEM cap) is reported as skipped, not compiled.
 
 The full run lands in ``BENCH_scaling.json`` at the repo root (plus
 ``results/benchmarks/scaling.json``) so subsequent PRs have a perf
@@ -183,8 +186,9 @@ def _make_clustered(v: int, seed: int, cross_edges: float):
 
 def _sharded_worker(size: int, shards: int, iters: int, seed: int) -> dict:
     """Measure the hierarchical ``sharded_fused`` path on ``shards``
-    virtual CPU devices.  Runs in a subprocess: XLA_FLAGS must be set
-    before jax is imported, and the parent keeps exactly one device.
+    devices: the chips, in the benchmark's own process, on a TPU host;
+    virtual CPU devices in a subprocess elsewhere (XLA_FLAGS must be set
+    before jax is imported, and the parent keeps exactly one device).
 
     Reports per-shard and aggregate edge-iters/s plus two references
     measured in the same process: the single-device fused path at the
@@ -197,8 +201,10 @@ def _sharded_worker(size: int, shards: int, iters: int, seed: int) -> dict:
     import time as _time
 
     from repro.api import Problem, Solver, SolverConfig
+    from repro.api.losses import SquaredLoss
     from repro.core.distributed import (shard_problem_fused,
                                         solve_nlasso_hier)
+    from repro.core.graph import fused_window_bytes, fused_window_cap
     from repro.core.mesh import make_host_mesh
 
     cross = 0.007 * size
@@ -206,10 +212,22 @@ def _sharded_worker(size: int, shards: int, iters: int, seed: int) -> dict:
     g, data = _make_clustered(size, seed, cross)
     build_s = _time.perf_counter() - t0
 
+    # plan under the fused window cap, as sharded_fused does
+    nf, cap = data.num_features, fused_window_cap()
+    pf = SquaredLoss().prox_param_floats(data.x.shape[1], nf)
+    hint = (nf, pf, 4, cap)
     t0 = _time.perf_counter()
-    sp = shard_problem_fused(g, data, shards, seed=seed)
+    sp = shard_problem_fused(g, data, shards, seed=seed, window_hint=hint)
     plan_s = _time.perf_counter() - t0
     h = sp.hier
+    row = {"size": int(size), "edges": int(g.num_edges),
+           "shards": int(shards), "iters": int(iters),
+           "build_s": build_s, "plan_s": plan_s}
+    window = fused_window_bytes(h.block_nodes, h.block_edges, h.kn, h.klo,
+                                h.khi, nf, param_floats=pf)
+    if window > cap:            # the chip's compiler would refuse it
+        return {**row, "skipped": f"per-shard fused window {window} B > "
+                                  f"VMEM cap {cap} B"}
     mesh = make_host_mesh(shards, 1)
 
     def time_hier():
@@ -244,7 +262,7 @@ def _sharded_worker(size: int, shards: int, iters: int, seed: int) -> dict:
 
     # single-shard hierarchical baseline at the same per-shard size (the
     # CI smoke gate is machine-relative against this)
-    sp1 = shard_problem_fused(gr, dr, 1, seed=seed)
+    sp1 = shard_problem_fused(gr, dr, 1, seed=seed, window_hint=hint)
     mesh1 = make_host_mesh(1, 1)
 
     def time_hier1():
@@ -260,16 +278,11 @@ def _sharded_worker(size: int, shards: int, iters: int, seed: int) -> dict:
     hier1_aggregate = gr.num_edges * time_hier1()
 
     return {
-        "size": int(size),
-        "edges": int(g.num_edges),
-        "shards": int(shards),
-        "iters": int(iters),
+        **row,
         "comm": comm,
         "cut_fraction": float(h.cut_fraction),
         "halo_nodes": int(h.halo_nodes),
         "replicated_edges": int(h.replicated_edges),
-        "build_s": build_s,
-        "plan_s": plan_s,
         "iters_per_s": its,
         "edge_iters_per_s": aggregate,
         "per_shard_edge_iters_per_s": aggregate / shards,
@@ -282,12 +295,24 @@ def _sharded_worker(size: int, shards: int, iters: int, seed: int) -> dict:
 
 def _run_sharded_rows(sizes, shards: int, iters: int, seed: int,
                       verbose: bool) -> dict:
-    """Spawn one subprocess per scale-out size (fresh XLA_FLAGS each)."""
+    """One row per scale-out size.  On TPU the rows run here, over every
+    chip of the host: this process holds the chips, so a child could not
+    reach them.  Off-TPU each row gets a subprocess with ``shards``
+    virtual CPU devices (fresh XLA_FLAGS each)."""
     import subprocess
     import sys
 
+    import jax
+
+    on_tpu = jax.default_backend() == "tpu"
     rows = {}
     for v in sizes:
+        if on_tpu:
+            rows[str(v)] = _sharded_worker(v, jax.device_count(), iters,
+                                           seed)
+            if verbose:
+                _print_sharded_row(v, rows[str(v)])
+            continue
         env = dict(os.environ)
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                             f" --xla_force_host_platform_device_count={shards}")
@@ -305,12 +330,19 @@ def _run_sharded_rows(sizes, shards: int, iters: int, seed: int,
         row = json.loads(res.stdout.strip().splitlines()[-1])
         rows[str(v)] = row
         if verbose:
-            print(f"|V|={v:>8d} |E|={row['edges']:>9d} S={shards} "
-                  f"comm={row['comm']} cut={row['cut_fraction']:.4f} "
-                  f"{row['iters_per_s']:7.3f}it/s "
-                  f"per-shard {row['per_shard_edge_iters_per_s']:.3e} "
-                  f"weak-scaling {row['weak_scaling_efficiency']:.3f}")
+            _print_sharded_row(v, row)
     return rows
+
+
+def _print_sharded_row(v: int, row: dict) -> None:
+    head = f"|V|={v:>8d} |E|={row['edges']:>9d} S={row['shards']} "
+    if "skipped" in row:
+        print(head + f"SKIPPED: {row['skipped']}")
+        return
+    print(head + f"comm={row['comm']} cut={row['cut_fraction']:.4f} "
+          f"{row['iters_per_s']:7.3f}it/s "
+          f"per-shard {row['per_shard_edge_iters_per_s']:.3e} "
+          f"weak-scaling {row['weak_scaling_efficiency']:.3f}")
 
 
 def _make(v: int, seed: int):
@@ -505,26 +537,31 @@ def run(seed: int = 0, verbose: bool = True, smoke: bool | None = None) -> dict:
               f"{obs_overhead['ratio']:.4f} "
               f"({'PASS' if obs_overhead['ok'] else 'FAIL'})")
 
-    # hierarchical scale-out rows (subprocess: multi-device CPU)
+    # hierarchical scale-out rows (the chips on TPU; off-TPU a subprocess
+    # on virtual CPU devices)
     sh_sizes = SMOKE_SHARDED_SIZES if smoke else SHARDED_SIZES
     sh_shards = SMOKE_SHARDED_SHARDS if smoke else SHARDED_SHARDS
     sh_iters = SMOKE_SHARDED_ITERS if smoke else SHARDED_ITERS
     sharded_rows = _run_sharded_rows(sh_sizes, sh_shards, sh_iters, seed,
                                      verbose)
     largest_sh = sharded_rows[str(sh_sizes[-1])]
+    measured = "skipped" not in largest_sh
     sharded = {
         "rows": sharded_rows,
-        "shards": sh_shards,
+        "shards": largest_sh["shards"],
         # full-run gate: device-parallel-equivalent per-shard throughput
         # of the largest row >= 0.7x the single-device fused path at the
         # matched per-shard size; smoke gate (CI): per-shard throughput
         # within 15% of the single-shard hierarchical baseline measured
-        # in the same run (machine-relative)
-        "ok": bool(largest_sh["per_shard_vs_single_shard"] >= 0.85
-                   if smoke else
-                   largest_sh["weak_scaling_efficiency"] >= 0.7),
+        # in the same run (machine-relative).  A skipped row (window over
+        # the VMEM cap) was not measured and does not pass.
+        "ok": measured and bool(
+            largest_sh["per_shard_vs_single_shard"] >= 0.85 if smoke else
+            largest_sh["weak_scaling_efficiency"] >= 0.7),
     }
-    if verbose:
+    if verbose and not measured:
+        print("sharded_fused gate: NOT MEASURED (largest row skipped)")
+    elif verbose:
         print(f"sharded_fused gate: "
               f"{'PASS' if sharded['ok'] else 'FAIL'} "
               f"(weak-scaling {largest_sh['weak_scaling_efficiency']:.3f}, "
@@ -569,6 +606,8 @@ if __name__ == "__main__":
     ap.add_argument("--shards", type=int, default=SHARDED_SHARDS)
     ap.add_argument("--iters", type=int, default=SHARDED_ITERS)
     args = ap.parse_args()
+    from benchmarks.common import use_compile_cache
+    use_compile_cache()
     if args.sharded_worker:
         print(json.dumps(_sharded_worker(args.size, args.shards,
                                          args.iters, args.seed),

@@ -292,4 +292,6 @@ if __name__ == "__main__":
                     help="short stream (CI smoke mode)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from benchmarks.common import use_compile_cache
+    use_compile_cache()
     run(seed=args.seed, smoke=args.smoke or None)

@@ -1,13 +1,15 @@
-"""Distributed nLasso: Algorithm 1 as shard_map message passing over 8
-(virtual) devices, with cluster-aware graph partitioning and boundary-only
-halo exchange — all through the unified Problem/Solver API (the "sharded"
-backend).
+"""Distributed nLasso: Algorithm 1 as shard_map message passing over
+every device of the process, with cluster-aware graph partitioning and
+boundary-only halo exchange — all through the unified Problem/Solver API
+(the "sharded" backend).  On a TPU host the shards are its chips; on a
+CPU the demo runs on 8 virtual host devices.
 
     PYTHONPATH=src python examples/distributed_nlasso.py
 """
 import os
 
-# MUST precede any jax import: 8 virtual host devices for the demo.
+# MUST precede any jax import: 8 virtual devices when the platform is the
+# CPU (the flag does not touch accelerators)
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
                            + os.environ.get("XLA_FLAGS", ""))
 
@@ -22,18 +24,19 @@ import numpy as np                                             # noqa: E402
 from repro.core import Problem, Solver, SolverConfig           # noqa: E402
 from repro.core.distributed import shard_problem               # noqa: E402
 from repro.data.synthetic import make_sbm_regression           # noqa: E402
-from repro.core.mesh import make_host_mesh                   # noqa: E402
+from repro.core.mesh import make_device_mesh                 # noqa: E402
 
 ds = make_sbm_regression(seed=0, cluster_sizes=(150, 150), p_in=0.5,
                          p_out=1e-3, num_labeled=30)
-mesh = make_host_mesh(8, 1)
+mesh = make_device_mesh()
+shards = mesh.shape["data"]
 problem = Problem.create(ds.graph, ds.data, lam=1e-3)
 print(f"mesh: {dict(mesh.shape)}  graph: |V|={ds.graph.num_nodes} "
       f"|E|={ds.graph.num_edges}")
 
 for partitioner in ("block", "cluster"):
     # partition statistics (the layout the sharded backend will build)
-    prob = shard_problem(ds.graph, ds.data, 8, partitioner=partitioner)
+    prob = shard_problem(ds.graph, ds.data, shards, partitioner=partitioner)
     print(f"\npartitioner={partitioner}: cut edges {prob.plan.cut_edges} "
           f"/ {ds.graph.num_edges}, boundary nodes "
           f"{prob.plan.boundary_nodes} / {ds.graph.num_nodes}")

@@ -39,6 +39,7 @@ Python hook that must fire between chunks.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
 import weakref
 from typing import Callable
@@ -50,7 +51,8 @@ import numpy as np
 from repro.api.losses import Loss, SquaredLoss
 from repro.api.problem import Problem, SolveResult, SolverConfig
 from repro.api.regularizers import Regularizer, TotalVariation
-from repro.core.graph import graph_signal_mse
+from repro.core.graph import (edge_ends_store, fused_window_bytes,
+                              fused_window_cap, graph_signal_mse)
 from repro.core.losses import NodeData
 from repro.core.partition import gather_padded
 from repro.engine import (DenseExecutor, certificate, device_loop,
@@ -556,38 +558,43 @@ def _fused_supported(problem: Problem, config: SolverConfig) -> bool:
             and config.clip_fn is None and config.affine_fn is None)
 
 
-def _fused_window_cap() -> int:
-    """Max per-grid-step VMEM window; degenerate layouts fall back."""
-    env = os.environ.get("REPRO_FUSED_MAX_WINDOW_BYTES")
-    if env:
-        return int(env)
-    # real VMEM budget on TPU; effectively uncapped for the jnp reference
-    return (12 << 20) if jax.default_backend() == "tpu" else (1 << 62)
+def _param_floats(problem: Problem) -> int | None:
+    """The loss's per-node prox words in a VMEM window, or None for a
+    custom loss that gives no estimate."""
+    try:
+        return problem.loss.prox_param_floats(problem.data.x.shape[1],
+                                              problem.num_features)
+    except NotImplementedError:
+        return None
+
+
+def _fused_window(problem: Problem, config: SolverConfig | None = None):
+    """Plan (or fetch) the graph's layout and size its VMEM window.
+
+    Returns ``(layout, window_bytes, cap)``; ``window_bytes`` is None for
+    a custom loss with no VMEM estimate (which then takes the unfused
+    route rather than crash the dispatch gate).  The estimate is
+    dtype-aware: the storage policy's itemsize scales the
+    state/prox-parameter blocks (``EdgeBlockLayout.window_bytes``), so
+    bf16 widens the fusable window instead of falling back to the
+    unfused path early.
+    """
+    pf = _param_floats(problem)
+    itemsize = 4 if config is None else jnp.dtype(config.dtype).itemsize
+    cap = fused_window_cap()
+    nf = problem.num_features
+    lt = _graph_layout(problem.graph, window_hint=(
+        nf, pf or 0, itemsize, cap))
+    window = (None if pf is None else
+              lt.window_bytes(nf, param_floats=pf, itemsize=itemsize))
+    return lt, window, cap
 
 
 def _fused_window_fits(problem: Problem,
                        config: SolverConfig | None = None) -> bool:
-    """Plan (or fetch) the graph's layout and check the VMEM window cap.
-
-    The estimate is dtype-aware: the storage policy's itemsize scales
-    the state/prox-parameter traffic (``EdgeBlockLayout.window_bytes``),
-    so bf16 roughly doubles the fusable window instead of falling back
-    to the unfused path early.
-    """
-    try:
-        param_floats = problem.loss.prox_param_floats(
-            problem.data.x.shape[1], problem.num_features)
-    except NotImplementedError:
-        # a custom loss with prox_setup but no VMEM estimate: fall back
-        # to the unfused path rather than crash the dispatch gate
-        return False
-    itemsize = 4 if config is None else jnp.dtype(config.dtype).itemsize
-    cap = _fused_window_cap()
-    lt = _graph_layout(problem.graph, window_hint=(
-        problem.num_features, param_floats, itemsize, cap))
-    return lt.window_bytes(
-        problem.num_features, param_floats=param_floats,
-        itemsize=itemsize) <= cap
+    """Whether the planned layout's window fits the VMEM cap."""
+    _, window, cap = _fused_window(problem, config)
+    return window is not None and window <= cap
 
 
 def _should_fuse(problem: Problem, config: SolverConfig) -> bool:
@@ -631,7 +638,7 @@ def _fused_setup(graph, data, lam, w_true, layout_arrays, *, loss, reg,
         else lt.pad_node_store(params[k])
         for k in pkeys)
     tau_s = lt.pad_node_store(tau_l[:, None])
-    src2, dst2 = src_l[:, None], dst_l[:, None]
+    ends = edge_ends_store(src_l, dst_l, lt.klo, lt.khi, lt.block_edges)
     sig2 = sig_l[:, None]
     la2 = (lam * weights_l)[:, None]
     unlabeled = 1.0 - data.labeled_mask
@@ -645,13 +652,11 @@ def _fused_setup(graph, data, lam, w_true, layout_arrays, *, loss, reg,
             mse = graph_signal_mse(w, w_true, unlabeled)
         return obj, mse
 
-    return (params_s, pkeys, tau_l, tau_s, sig_l, sig2, src2, dst2, la2,
-            metrics)
+    return (params_s, pkeys, tau_l, tau_s, sig_l, sig2, ends, la2, metrics)
 
 
-def _fused_run_iters(lt, inc_e, inc_s, params_s, pkeys, tau_s, src2, dst2,
-                     sig2, la2, *, loss, reg, rho, use_kernel,
-                     compute_residual: bool = False):
+def _fused_run_iters(lt, ends, params_s, pkeys, tau_s, sig2, la2, *, loss,
+                     reg, rho, use_kernel, compute_residual: bool = False):
     """Build ``run_iters(state, iters)`` advancing the padded stores.
 
     The scan carries the *padded* stores: the halo padding rows are
@@ -670,8 +675,8 @@ def _fused_run_iters(lt, inc_e, inc_s, params_s, pkeys, tau_s, src2, dst2,
     def run_iters(state, iters):
         w_store, u_store = state
         out = ops.pd_step(
-            w_store, u_store, inc_e, inc_s, params_s, tau_s, src2, dst2,
-            sig2, la2, loss=loss, reg=reg, pkeys=pkeys, block_nodes=bv,
+            w_store, u_store, ends, params_s, tau_s, sig2, la2, loss=loss,
+            reg=reg, pkeys=pkeys, block_nodes=bv,
             block_edges=eb, kn=kn, klo=klo, khi=khi, rho=rho, iters=iters,
             compute_residual=compute_residual, use_kernel=use_kernel)
         if compute_residual:
@@ -687,7 +692,7 @@ def _fused_run_iters(lt, inc_e, inc_s, params_s, pkeys, tau_s, src2, dst2,
 
 
 def _fused_scan_impl(graph, data, w0_l, u0_l, lam, w_true, layout_arrays,
-                     inc_arrays, *, loss: Loss, reg: Regularizer,
+                     *, loss: Loss, reg: Regularizer,
                      layout, num_iters: int, rho: float, metric_every: int,
                      use_kernel: bool, record_residual: bool = False,
                      dtype: str = "float32"):
@@ -696,7 +701,7 @@ def _fused_scan_impl(graph, data, w0_l, u0_l, lam, w_true, layout_arrays,
     engine's formulas) on the cadence.
 
     ``layout`` is static (block extents); the layout's arrays come in as
-    the traced ``layout_arrays``/``inc_arrays`` tuples so they stay
+    the traced ``layout_arrays`` tuple so they stay
     device buffers rather than jaxpr constants.  ``dtype`` is the
     storage policy for the scanned state and prox parameters (bf16
     halves the window traffic; accumulation stays f32 — see
@@ -706,15 +711,13 @@ def _fused_scan_impl(graph, data, w0_l, u0_l, lam, w_true, layout_arrays,
     lt = layout
     store_dt = jnp.dtype(dtype)
     w0_l, u0_l = w0_l.astype(store_dt), u0_l.astype(store_dt)
-    inc_e, inc_s = inc_arrays
-    (params_s, pkeys, tau_l, tau_s, sig_l, sig2, src2, dst2, la2,
+    (params_s, pkeys, tau_l, tau_s, sig_l, sig2, ends, la2,
      metrics) = _fused_setup(graph, data, lam, w_true, layout_arrays,
                              loss=loss, reg=reg, layout=lt, dtype=dtype)
 
     run_iters = _fused_run_iters(
-        lt, lt.pad_node_store(inc_e), lt.pad_node_store(inc_s), params_s,
-        pkeys, tau_s, src2, dst2, sig2, la2, loss=loss, reg=reg, rho=rho,
-        use_kernel=use_kernel)
+        lt, ends, params_s, pkeys, tau_s, sig2, la2, loss=loss, reg=reg,
+        rho=rho, use_kernel=use_kernel)
 
     eb, klo, khi = lt.block_edges, lt.klo, lt.khi
 
@@ -758,8 +761,8 @@ _fused_scan = _jit(_fused_scan_impl,
 
 
 def _fused_tol_impl(graph, data, w_store0, u_store0, lam, w_true,
-                    node_inv, inc_stores, params_s, tau_s, sig2,
-                    edge_cols, tol, *, loss: Loss, reg: Regularizer,
+                    node_inv, ends, params_s, tau_s, sig2, la2, tol, *,
+                    loss: Loss, reg: Regularizer,
                     layout, pkeys, num_iters: int, rho: float,
                     metric_every: int, use_kernel: bool):
     """Device-resident fused tol engine: the ``lax.while_loop`` driver
@@ -776,13 +779,9 @@ def _fused_tol_impl(graph, data, w_store0, u_store0, lam, w_true,
     scans single launches, each returning its per-launch residual max.
     """
     lt = layout
-    inc_e_s, inc_s_s = inc_stores
-    src2, dst2, la2 = edge_cols
-
     run_iters = _fused_run_iters(
-        lt, inc_e_s, inc_s_s, params_s, pkeys, tau_s, src2, dst2, sig2,
-        la2, loss=loss, reg=reg, rho=rho, use_kernel=use_kernel,
-        compute_residual=True)
+        lt, ends, params_s, pkeys, tau_s, sig2, la2, loss=loss, reg=reg,
+        rho=rho, use_kernel=use_kernel, compute_residual=True)
 
     eb, klo = lt.block_edges, lt.klo
     metrics = make_metrics_fn(loss, reg, graph, data, lam, w_true)
@@ -844,13 +843,12 @@ def _solve_fused(problem: Problem, config: SolverConfig, *, w0=None,
 
     layout_arrays = (lt.node_perm, lt.node_inv, lt.src, lt.dst, lt.weights,
                      lt.edge_pos)
-    inc_arrays = (lt.inc_edges, lt.inc_signs)
     use_kernel = ops._use_kernel_default()
     if config.tol is None or config.num_iters == 0:
         # 0-iteration budget: degenerate 0-length scan, no while loop
         w_l, u_l, obj, mse, res = _fused_scan(
             problem.graph, data, w0_l, u0_l, problem.lam, w_true,
-            layout_arrays, inc_arrays, loss=problem.loss,
+            layout_arrays, loss=problem.loss,
             reg=problem.regularizer, layout=lt,
             num_iters=config.num_iters, rho=config.rho,
             metric_every=config.metric_every, use_kernel=use_kernel,
@@ -860,21 +858,19 @@ def _solve_fused(problem: Problem, config: SolverConfig, *, w0=None,
         # per-solve setup (layout gathers, prox params, padded
         # stepsizes) runs once, eagerly; the while loop advances the
         # padded stores in the storage dtype
-        (params_s, pkeys, tau_l, tau_s, sig_l, sig2, src2, dst2, la2,
+        (params_s, pkeys, tau_l, tau_s, sig_l, sig2, ends, la2,
          _metrics) = _fused_setup(
             problem.graph, data, problem.lam, w_true, layout_arrays,
             loss=problem.loss, reg=problem.regularizer, layout=lt,
             dtype=dtype)
         eb, klo = lt.block_edges, lt.klo
-        inc_stores = (lt.pad_node_store(lt.inc_edges),
-                      lt.pad_node_store(lt.inc_signs))
         store0 = (lt.pad_node_store(w0_l).astype(store_dt),
                   jnp.pad(u0_l, ((klo * eb, lt.khi * eb),
                                  (0, 0))).astype(store_dt))
         w_store, u_store, obj, mse, res, its = _fused_tol(
             problem.graph, data, store0[0], store0[1], problem.lam,
-            w_true, lt.node_inv, inc_stores, params_s, tau_s, sig2,
-            (src2, dst2, la2), config.tol, loss=problem.loss,
+            w_true, lt.node_inv, ends, params_s, tau_s, sig2, la2,
+            config.tol, loss=problem.loss,
             reg=problem.regularizer, layout=lt, pkeys=pkeys,
             num_iters=config.num_iters, rho=config.rho,
             metric_every=config.metric_every, use_kernel=use_kernel)
@@ -912,12 +908,28 @@ def solve_pallas(problem: Problem, config: SolverConfig, *, w0=None,
     affine prox through ``kernels.ops.batched_affine``;
     ``config.clip_fn``/``config.affine_fn`` override either (and disable
     fusion).
+
+    Which of the two ran is recorded in ``diagnostics["route"]``:
+    ``fused``, and for a planned layout its block extents, the VMEM
+    window estimate and the cap it was held to — a window over the cap
+    takes the unfused kernels, and the route says so.
     """
-    if _should_fuse(problem, config):
-        return _solve_fused(problem, config, w0=w0, u0=u0, w_true=w_true)
-    clip_fn, affine_fn = resolve_kernel_hooks(problem, config, True)
-    return _solve_dense(problem, config, w0=w0, u0=u0, w_true=w_true,
-                        clip_fn=clip_fn, affine_fn=affine_fn)
+    fused = _should_fuse(problem, config)
+    if fused:
+        res = _solve_fused(problem, config, w0=w0, u0=u0, w_true=w_true)
+    else:
+        clip_fn, affine_fn = resolve_kernel_hooks(problem, config, True)
+        res = _solve_dense(problem, config, w0=w0, u0=u0, w_true=w_true,
+                           clip_fn=clip_fn, affine_fn=affine_fn)
+    route = {"fused": fused}
+    if fused or (_fused_enabled(config)
+                 and _fused_supported(problem, config)):
+        lt, window, cap = _fused_window(problem, config)
+        route.update(block_nodes=lt.block_nodes, num_blocks=lt.num_blocks,
+                     kn=lt.kn, klo=lt.klo, khi=lt.khi, window_bytes=window,
+                     window_cap=cap)
+    return dataclasses.replace(res, diagnostics={**res.diagnostics,
+                                                 "route": route})
 
 
 # ---------------------------------------------------------------------------
@@ -978,14 +990,14 @@ def solve_sharded(problem: Problem, config: SolverConfig, *, w0=None,
                                       permute_node_array_device,
                                       unpermute_edge_array_device,
                                       unpermute_node_array_device)
-    from repro.core.mesh import make_host_mesh
+    from repro.core.mesh import make_device_mesh
 
     if not problem.regularizer.fusable:
         raise NotImplementedError(
             "sharded backend needs an edge-elementwise (fusable) "
             "regularizer resolvent")
 
-    mesh = config.mesh if config.mesh is not None else make_host_mesh(1, 1)
+    mesh = config.mesh if config.mesh is not None else make_device_mesh()
     num_shards = (config.num_shards if config.num_shards is not None
                   else mesh.shape[config.mesh_axis])
     sp = shard_problem(problem.graph, problem.data, num_shards,
@@ -1058,7 +1070,7 @@ def solve_sharded_fused(problem: Problem, config: SolverConfig, *, w0=None,
     from repro.core.distributed import (halo_exchange_bytes_per_iter,
                                         resolve_comm, shard_problem_fused,
                                         solve_nlasso_hier)
-    from repro.core.mesh import make_host_mesh
+    from repro.core.mesh import make_device_mesh
 
     if not problem.regularizer.fusable:
         raise NotImplementedError(
@@ -1072,18 +1084,27 @@ def solve_sharded_fused(problem: Problem, config: SolverConfig, *, w0=None,
         raise NotImplementedError(
             "custom kernel hooks target the unfused engine")
 
-    mesh = config.mesh if config.mesh is not None else make_host_mesh(1, 1)
+    mesh = config.mesh if config.mesh is not None else make_device_mesh()
     num_shards = (config.num_shards if config.num_shards is not None
                   else mesh.shape[config.mesh_axis])
-    try:
-        param_floats = problem.loss.prox_param_floats(
-            problem.data.x.shape[1], problem.num_features)
-    except NotImplementedError:
-        param_floats = 0
-    hint = (problem.num_features, param_floats, 4, _fused_window_cap())
+    pf = _param_floats(problem) or 0
+    cap = fused_window_cap()
     sp = shard_problem_fused(problem.graph, problem.data, num_shards,
                              partitioner=config.partitioner,
-                             loss=problem.loss, window_hint=hint)
+                             loss=problem.loss,
+                             window_hint=(problem.num_features, pf, 4, cap))
+    h = sp.hier
+    window = fused_window_bytes(h.block_nodes, h.block_edges, h.kn, h.klo,
+                                h.khi, problem.num_features,
+                                param_floats=pf)
+    if window > cap:
+        # never hand the chip's compiler a kernel it would refuse
+        raise ValueError(
+            f"sharded_fused ({num_shards} shards, {h.num_blocks} blocks "
+            f"per shard): the fused window needs {window} bytes of VMEM "
+            f"(block_nodes={h.block_nodes}, block_edges={h.block_edges}, "
+            f"kn={h.kn}, klo={h.klo}, khi={h.khi}) but the cap is {cap} "
+            "bytes; use more shards or backend='sharded'")
     lam = float(problem.lam)
     w_np, u_np, iterations, comm = solve_nlasso_hier(
         sp, mesh, lam, config.num_iters, axis=config.mesh_axis,

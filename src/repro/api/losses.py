@@ -40,8 +40,15 @@ from typing import Callable, ClassVar
 
 import jax
 import jax.numpy as jnp
+from jax.scipy.linalg import cho_solve
 
+from repro.core.graph import vmem_words
 from repro.core.losses import NodeData
+
+# (m, n)-shaped temporaries of the logistic prox's Newton step that the
+# v5e compiler keeps live in the fused kernel (fitted with the window
+# model, core.graph.fused_window_bytes)
+_LOGISTIC_TEMPS = 4
 
 LOSSES: dict[str, type] = {}
 
@@ -153,7 +160,9 @@ class Loss:
         raise NotImplementedError
 
     def prox_param_floats(self, num_samples: int, num_features: int) -> int:
-        """Per-node fp32 count of ``prox_setup`` leaves (VMEM budgeting)."""
+        """Per-node f32 words the ``prox_setup`` leaves and the prox's
+        in-kernel temporaries take in a fused VMEM window, tiled
+        (``core.graph.vmem_words``; VMEM budgeting)."""
         raise NotImplementedError
 
     def make_prox(self, data: NodeData, tau: jnp.ndarray, *,
@@ -175,7 +184,7 @@ class SquaredLoss(Loss):
     kernel_safe: ClassVar[bool] = True
 
     def node_values(self, data, w):
-        pred = jnp.einsum("vmn,vn->vm", data.x, w)
+        pred = jnp.einsum("vmn,vn->vm", data.x, w, precision="highest")
         res = (data.y - pred) ** 2 * data.sample_mask
         return jnp.sum(res, axis=1) / data.counts()
 
@@ -188,13 +197,18 @@ class SquaredLoss(Loss):
         nodes get P = I, b = 0.
         """
         xm = data.x * data.sample_mask[..., None]
-        q = jnp.einsum("vmn,vmk->vnk", xm, data.x)            # (V, n, n)
-        xty = jnp.einsum("vmn,vm->vn", xm, data.y)            # (V, n)
+        q = jnp.einsum("vmn,vmk->vnk", xm, data.x,
+                       precision="highest")                   # (V, n, n)
+        xty = jnp.einsum("vmn,vm->vn", xm, data.y,
+                         precision="highest")                 # (V, n)
         c = (2.0 * tau / data.counts())[:, None]              # (V, 1)
         n = data.num_features
         eye = jnp.eye(n, dtype=data.x.dtype)
-        a = eye[None] + c[..., None] * q
-        p = jnp.linalg.inv(a)
+        a = eye[None] + c[..., None] * q                      # SPD
+        # through a Cholesky factor, not jnp.linalg.inv: the TPU compiler
+        # takes minutes over the batched LU at 1M nodes
+        p = cho_solve((jnp.linalg.cholesky(a), True),
+                      jnp.broadcast_to(eye, a.shape))
         b = c * xty
         lab = data.labeled_mask
         p = jnp.where(lab[:, None, None] > 0, p, eye[None])
@@ -205,11 +219,11 @@ class SquaredLoss(Loss):
         vb = v + params["b"]
         if affine_fn is not None:
             return affine_fn(params["p"], vb)
-        return jnp.einsum("vnk,vk->vn", params["p"], vb)
+        return jnp.einsum("vnk,vk->vn", params["p"], vb, precision="highest")
 
     def prox_param_floats(self, num_samples, num_features):
         n = num_features
-        return n * n + n
+        return vmem_words((n, n), (n,))
 
 
 @register_loss("lasso")
@@ -234,8 +248,8 @@ class LassoLoss(Loss):
 
     def prox_setup(self, data, tau):
         xm = data.x * data.sample_mask[..., None]
-        q = jnp.einsum("vmn,vmk->vnk", xm, data.x)
-        xty = jnp.einsum("vmn,vm->vn", xm, data.y)
+        q = jnp.einsum("vmn,vmk->vnk", xm, data.x, precision="highest")
+        xty = jnp.einsum("vmn,vm->vn", xm, data.y, precision="highest")
         m = data.counts()
         # lambda_max via eigvalsh (setup-time only; n is small)
         lam_max = jnp.linalg.eigvalsh(q)[:, -1]
@@ -250,7 +264,8 @@ class LassoLoss(Loss):
         m, step, tau = params["m"], params["step"], params["tau"]
 
         def body(_, z):
-            grad = 2.0 * (jnp.einsum("vnk,vk->vn", q, z) - xty) / m
+            grad = 2.0 * (jnp.einsum("vnk,vk->vn", q, z, precision="highest")
+                          - xty) / m
             grad = grad + (z - v) / tau
             return _soft_threshold(z - step * grad, self.alpha * step)
 
@@ -259,7 +274,7 @@ class LassoLoss(Loss):
 
     def prox_param_floats(self, num_samples, num_features):
         n = num_features
-        return n * n + n + 4
+        return vmem_words((n, n), (n,), (1,), (1,), (1,), (1,))
 
 
 @register_loss("logistic")
@@ -282,7 +297,7 @@ class LogisticLoss(Loss):
     kernel_safe: ClassVar[bool] = True
 
     def node_values(self, data, w):
-        logits = jnp.einsum("vmn,vn->vm", data.x, w)
+        logits = jnp.einsum("vmn,vn->vm", data.x, w, precision="highest")
         # numerically-stable BCE with logits
         per = jnp.maximum(logits, 0.0) - logits * data.y + jnp.log1p(
             jnp.exp(-jnp.abs(logits)))
@@ -299,13 +314,16 @@ class LogisticLoss(Loss):
         m, tau = params["m"], params["tau"]
 
         def body(_, z):
-            logits = jnp.einsum("vmn,vn->vm", x, z)
+            logits = jnp.einsum("vmn,vn->vm", x, z, precision="highest")
             s = jax.nn.sigmoid(logits)
             r = (s - y) * mask                                   # (V, m)
-            grad = jnp.einsum("vm,vmn->vn", r, x) / m
+            # a multiply-reduce, not a batched dot: the TPU kernel
+            # compiler lowers no dot form of this contraction over m
+            grad = jnp.sum(r[..., None] * x, axis=1) / m
             grad = grad + (z - v) / tau
             d = (s * (1 - s)) * mask                             # (V, m)
-            hess = jnp.einsum("vm,vmn,vmk->vnk", d, x, x) / m[..., None]
+            hess = jnp.einsum("vmn,vmk->vnk", x * d[..., None], x,
+                              precision="highest") / m[..., None]
             n = z.shape[1]
             hess = hess + jnp.eye(n, dtype=z.dtype)[None] / tau[..., None]
             return z - _chol_solve(hess, grad)
@@ -314,7 +332,11 @@ class LogisticLoss(Loss):
         return jnp.where(params["labeled"] > 0, z, v)
 
     def prox_param_floats(self, num_samples, num_features):
-        return num_samples * (num_features + 2) + 3
+        m, n = num_samples, num_features
+        # the Newton step's (m, n)-shaped products live beside the
+        # leaves: the v5e compiler keeps _LOGISTIC_TEMPS of them
+        return (vmem_words((m, n), (m,), (m,), (1,), (1,), (1,))
+                + _LOGISTIC_TEMPS * vmem_words((m, n)))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -131,7 +131,8 @@ class SolverConfig:
                    (anything but squared + TV) or when custom kernel
                    hooks are set.
       mesh / mesh_axis / num_shards / partitioner / comm: sharded-backend
-                   layout knobs (mesh defaults to a (1, 1) host mesh).
+                   layout knobs (mesh defaults to every device of the
+                   process, all on the "data" axis).
                    ``comm`` is "auto" (boundary exchange when the
                    inter-shard cut fraction is < 25%, dense otherwise),
                    "dense", or "boundary".
@@ -152,9 +153,9 @@ class SolverConfig:
       dtype:       storage dtype for the iteration state on the fused
                    pallas path: "float32" (default) or "bfloat16".
                    bf16 stores ``w`` / ``u`` and the prox parameters at
-                   2 bytes — halving the HBM<->VMEM window traffic and
-                   roughly doubling the fusable graph size — while every
-                   gather-sum, prox solve, and dual resolvent still
+                   2 bytes — halving the HBM<->VMEM window traffic —
+                   while every incidence contraction, prox solve, and
+                   dual resolvent still
                    *accumulates* in f32 (upcast at the VMEM window
                    boundary, see ``kernels.ref.pd_window_step``).
                    Returned ``w`` / ``u`` and all traces are f32.  Note

@@ -32,9 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import losses as L
-from repro.core.graph import EmpiricalGraph
+from repro.core.graph import EmpiricalGraph, edge_ends_store
 from repro.core.partition import (HierarchyPlan, PartitionPlan,
                                   block_partition, cluster_partition,
                                   plan_hierarchy, plan_partition,
@@ -172,7 +171,7 @@ def _make_sharded_run(problem: ShardedProblem, mesh: Mesh, lam: float,
     if with_residual:
         out_specs = out_specs + (edge_spec,)
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(node_spec, edge_spec, node_spec,
                        edge_spec, edge_spec, edge_spec, node_spec) + pspecs,
              out_specs=out_specs)
@@ -280,7 +279,8 @@ class HierarchicalProblem:
 
     Node-store arrays are stacked per shard at ``w_store_rows`` rows each
     (owned+halo layout rows plus the fused kernel's inert suffix
-    padding); edge tables at ``edges_pad`` owned slots per shard.
+    padding); edge tables at ``edges_pad`` owned slots per shard, except
+    ``ends``, which follows the dual store's ``u_store_rows``.
     """
     hier: HierarchyPlan
     loss: object
@@ -288,12 +288,9 @@ class HierarchicalProblem:
     # node stores (S * WSR, ...)
     tau: jnp.ndarray
     prox_params: dict
-    inc_edges: jnp.ndarray
-    inc_signs: jnp.ndarray
     node_owned: jnp.ndarray      # (S * NV, 1)
+    ends: jnp.ndarray            # (S * ESR, 2) (src, dst) per u-store row
     # owned edge slots (S * NE, 1)
-    src: jnp.ndarray
-    dst: jnp.ndarray
     bound_unit: jnp.ndarray      # A_e (0 for padding/replica-free slots)
     edge_owned: jnp.ndarray
     orient: jnp.ndarray
@@ -312,16 +309,6 @@ def _hier_gather(idx: np.ndarray, arr: np.ndarray, fill) -> np.ndarray:
     valid = idx >= 0
     out[valid] = arr[idx[valid]]
     return out
-
-
-def _pad_shard_rows(arr: np.ndarray, num_shards: int, rows_out: int):
-    """(S*rows, ...) -> (S*rows_out, ...) appending zero rows per shard."""
-    rows = arr.shape[0] // num_shards
-    pad = np.zeros((num_shards, rows_out - rows) + arr.shape[1:],
-                   dtype=arr.dtype)
-    stacked = np.concatenate(
-        [arr.reshape((num_shards, rows) + arr.shape[1:]), pad], axis=1)
-    return stacked.reshape((num_shards * rows_out,) + arr.shape[1:])
 
 
 def shard_problem_fused(graph: EmpiricalGraph, data: L.NodeData,
@@ -351,7 +338,7 @@ def shard_problem_fused(graph: EmpiricalGraph, data: L.NodeData,
     hier = plan_hierarchy(graph, assign, num_shards,
                           window_hint=window_hint)
     S = hier.num_shards
-    WSR = hier.w_store_rows
+    NE = hier.edges_pad
 
     tau_full = np.asarray(graph.primal_stepsizes(), np.float32)
     tau = _hier_gather(hier.w_inj, tau_full, 1.0)[:, None]
@@ -366,12 +353,11 @@ def shard_problem_fused(graph: EmpiricalGraph, data: L.NodeData,
         hier=hier, loss=loss_obj, num_features=int(data.num_features),
         tau=jnp.asarray(tau),
         prox_params={k: jnp.asarray(v) for k, v in params.items()},
-        inc_edges=jnp.asarray(
-            _pad_shard_rows(hier.inc_edges, S, WSR), jnp.int32),
-        inc_signs=jnp.asarray(_pad_shard_rows(hier.inc_signs, S, WSR)),
         node_owned=jnp.asarray(hier.node_owned[:, None]),
-        src=jnp.asarray(hier.src[:, None], jnp.int32),
-        dst=jnp.asarray(hier.dst[:, None], jnp.int32),
+        ends=jnp.concatenate([
+            edge_ends_store(hier.src[sl], hier.dst[sl], hier.klo,
+                            hier.khi, hier.block_edges)
+            for sl in (slice(s * NE, (s + 1) * NE) for s in range(S))]),
         bound_unit=jnp.asarray(hier.weights[:, None]),
         edge_owned=jnp.asarray(hier.edge_owned[:, None]),
         orient=jnp.asarray(hier.orient[:, None]),
@@ -442,8 +428,7 @@ def _make_hier_run(problem: HierarchicalProblem, mesh: Mesh, lam: float,
                 else problem.recv_src_dense)
 
     sharded = lambda a: P(axis, *(None,) * (a.ndim - 1))  # noqa: E731
-    fixed = (problem.tau, problem.inc_edges, problem.inc_signs,
-             problem.node_owned, problem.src, problem.dst,
+    fixed = (problem.tau, problem.node_owned, problem.ends,
              problem.bound_unit, problem.edge_owned, problem.orient,
              problem.send_idx, problem.send_flip, recv_src,
              problem.recv_flip)
@@ -453,9 +438,12 @@ def _make_hier_run(problem: HierarchicalProblem, mesh: Mesh, lam: float,
     if with_residual:
         out_specs = out_specs + (P(axis),)
 
-    @partial(shard_map, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    def run(w_store, u_store, tau, inc_e, inc_s, n_own, src, dst, wts,
-            e_own, orient, send_idx, send_flip, rsrc, rflip, *pvals):
+    # check_vma=False: the fused kernel's pallas_call declares its
+    # outputs without per-axis variance, which the checker requires
+    @partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
+             out_specs=out_specs, check_vma=False)
+    def run(w_store, u_store, tau, n_own, ends, wts, e_own, orient,
+            send_idx, send_flip, rsrc, rflip, *pvals):
         executor = HierarchicalExecutor(
             axis=axis, comm=comm, num_blocks=nb, block_nodes=BV,
             block_edges=EB, klo=klo, node_owned=n_own, edge_owned=e_own,
@@ -463,16 +451,14 @@ def _make_hier_run(problem: HierarchicalProblem, mesh: Mesh, lam: float,
             recv_src=rsrc, recv_flip=rflip)
         sig = jnp.full((NE, 1), 0.5, jnp.float32)
         la = lam * wts
-        src1, dst1 = src, dst
 
         def body(state, _):
             w_s, u_s = state
             u_r = executor.refresh_duals(u_s)
             w_new, u_new = ops.pd_step(
-                w_s, u_r, inc_e, inc_s, pvals, tau, src1, dst1, sig, la,
-                loss=loss, reg=reg, pkeys=pkeys, block_nodes=BV,
-                block_edges=EB, kn=kn, klo=klo, khi=khi, rho=rho,
-                iters=1, compute_residual=False)
+                w_s, u_r, ends, pvals, tau, sig, la, loss=loss, reg=reg,
+                pkeys=pkeys, block_nodes=BV, block_edges=EB, kn=kn,
+                klo=klo, khi=khi, rho=rho, iters=1, compute_residual=False)
             res = None
             if with_residual:
                 res = executor.residual(w_s, u_r, w_new, u_new, tau, sig)
@@ -519,8 +505,7 @@ def solve_nlasso_hier(problem: HierarchicalProblem, mesh: Mesh, lam: float,
                     for k in sorted(problem.prox_params))
     recv_src = (problem.recv_src_boundary if comm == "boundary"
                 else problem.recv_src_dense)
-    operands = (problem.tau, problem.inc_edges, problem.inc_signs,
-                problem.node_owned, problem.src, problem.dst,
+    operands = (problem.tau, problem.node_owned, problem.ends,
                 problem.bound_unit, problem.edge_owned, problem.orient,
                 problem.send_idx, problem.send_flip, recv_src,
                 problem.recv_flip) + pleaves

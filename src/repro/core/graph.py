@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import os
 import weakref
 from functools import partial
 
@@ -66,9 +67,6 @@ class EdgeBlockLayout:
       node_inv:      (V,) original node id -> layout pos.
       src, dst:      (nb*EB,) int32 endpoints in layout node ids (0 pads).
       weights:       (nb*EB,) float32 A_e (0.0 for padding slots).
-      inc_edges:     (nb*BV, max_deg) int32 *storage* edge ids
-                     (= owned position + klo*EB; 0-filled padding).
-      inc_signs:     (nb*BV, max_deg) float32 +1/-1/0 as EmpiricalGraph.
       edge_pos:      (E,) original edge id -> owned layout position.
       edge_flip:     (E,) +1/-1; u_layout = edge_flip * u_original.
     """
@@ -79,7 +77,6 @@ class EdgeBlockLayout:
     kn: int
     klo: int
     khi: int
-    max_degree: int
     num_nodes: int
     num_edges: int
     node_perm: jnp.ndarray
@@ -87,8 +84,6 @@ class EdgeBlockLayout:
     src: jnp.ndarray
     dst: jnp.ndarray
     weights: jnp.ndarray
-    inc_edges: jnp.ndarray
-    inc_signs: jnp.ndarray
     edge_pos: jnp.ndarray
     edge_flip: jnp.ndarray
 
@@ -110,26 +105,109 @@ class EdgeBlockLayout:
     def window_bytes(self, num_features: int,
                      param_floats: int | None = None,
                      itemsize: int = 4) -> int:
-        """VMEM footprint of one grid step's resident window.
+        """VMEM one grid step of the fused kernel needs (see
+        :func:`fused_window_bytes`)."""
+        return fused_window_bytes(
+            self.block_nodes, self.block_edges, self.kn, self.klo,
+            self.khi, num_features, param_floats=param_floats,
+            itemsize=itemsize)
 
-        ``param_floats`` is the per-node float count of the loss's prox
-        parameters (``Loss.prox_param_floats``); defaults to the squared
-        loss's affine map (P, b).  ``itemsize`` is the *storage* dtype's
-        byte width (4 for f32, 2 for bf16) — it scales the state and
-        prox-parameter traffic, so bf16 storage roughly doubles the
-        fusable window.  Index/step tensors (incidence ids+signs, tau,
-        src/dst/sigma/la) stay 4-byte regardless of the storage policy.
-        """
-        n = num_features
-        if param_floats is None:
-            param_floats = n * n + n                          # P, b
-        nw = self.kn * self.block_nodes
-        ew = (self.klo + 1 + self.khi) * self.block_edges
-        state = nw * (n + param_floats) + ew * n              # w, prox, u window
-        state += self.block_edges * n                         # u+ (owned)
-        index = nw * (1 + 2 * self.max_degree)                # tau, inc ids+signs
-        index += self.block_edges * 4                         # src/dst/sig/la
-        return itemsize * state + 4 * index
+
+# TPU VMEM holds an array in (8, 128) tiles over its two minor axes
+_SUBLANES, _LANES = 8, 128
+# live copies the v5e compiler keeps of the fused kernel's in-kernel
+# values (the bf16 incidence matrix, the f32 edge-window and node-window
+# values) and fixed scratch, fitted to the smallest vmem_limit_bytes it
+# accepts (bisected on compiles for a described v5e):
+#   layout (BV, EB, kn, klo, khi)   loss       accepted   estimate
+#   512x512 lattice (256,512,3,2,2) squared    19.2 MiB   36.3 MiB
+#   512x512 lattice (512,1024,2,1,1) squared   28.6 MiB   52.1 MiB
+#   512x512 lattice (256,512,3,2,2) logistic   54.2 MiB   76.8 MiB
+#   §5 SBM (304,11072,1,0,0), 10 iters squared 55.1 MiB   69.2 MiB
+#   1024^2 lattice / 4 shards (256,512,4,3,3)  17.7 MiB   58.0 MiB
+_INC_COPIES, _EDGE_COPIES = 5, 4
+_VMEM_SLACK = 2 << 20
+# per-TensorCore VMEM of a TPU v5e, the chip this repository targets
+_V5E_VMEM_BYTES = 128 << 20
+
+
+def vmem_words(*shapes: tuple) -> int:
+    """f32 words one node's (or edge's) values of the given per-row
+    ``shapes`` take in VMEM: the last axis padded to 128 lanes, the one
+    before it (if any) to 8 sublanes."""
+    words = 0
+    for shape in shapes:
+        shape = tuple(shape) or (1,)
+        lead = int(np.prod(shape[:-2], dtype=np.int64))
+        sub = _round_up(shape[-2], _SUBLANES) if len(shape) > 1 else 1
+        words += lead * sub * _round_up(shape[-1], _LANES)
+    return words
+
+
+def fused_window_bytes(block_nodes: int, block_edges: int, kn: int,
+                       klo: int, khi: int, num_features: int, *,
+                       param_floats: int | None = None,
+                       itemsize: int = 4) -> int:
+    """VMEM one grid step of the fused primal-dual kernel needs on a TPU.
+
+    Two parts.  The in-kernel values: the (EW, NW) bf16 signed incidence
+    matrix, the f32 edge-window state (a 2-wide feature axis fills a
+    128-lane row) and the f32 node-window state with the prox
+    parameters, times the live copies the compiler keeps (fitted; see
+    the table above).  And the streamed blocks, double-buffered:
+    ``itemsize`` is the *storage* dtype's width (4 for f32, 2 for bf16)
+    and scales the state and prox-parameter blocks, while the endpoint
+    and step operands stay 4-byte.
+
+    ``param_floats`` is the per-node word count of the loss's prox
+    parameters and in-kernel temporaries, tiled
+    (``Loss.prox_param_floats``); it defaults to the squared loss's
+    affine map (P, b).
+    """
+    bv, eb, n = block_nodes, block_edges, num_features
+    ktot = klo + 1 + khi
+    nw, ew = kn * bv, ktot * eb
+    if param_floats is None:
+        param_floats = vmem_words((n, n), (n,))
+    row = vmem_words((n,))
+    incidence = ew * _round_up(nw, _LANES) * 2
+    values = (_INC_COPIES * incidence + _EDGE_COPIES * ew * row * 4
+              + nw * (row + param_floats) * 4)
+    state = nw * param_floats + (nw + bv + ew + eb) * n
+    index = 2 * ew + nw + 2 * eb
+    return values + 2 * (itemsize * state + 4 * index) + _VMEM_SLACK
+
+
+def fused_vmem_cap() -> int:
+    """VMEM limit the fused kernel requests: 3/4 of the TensorCore's, the
+    rest left to the compiler (the v5e figure when no TPU is attached,
+    as when compiling for a described one)."""
+    cap = _V5E_VMEM_BYTES
+    if jax.default_backend() == "tpu":
+        from jax.experimental.pallas import tpu as pltpu
+        cap = pltpu.get_tpu_info().vmem_capacity_bytes
+    return cap * 3 // 4
+
+
+def fused_window_cap() -> int:
+    """Largest fused window the routers accept: ``fused_vmem_cap()`` on
+    a TPU, where the kernel is compiled, and uncapped elsewhere (the jnp
+    reference and interpret mode hold no VMEM).
+    ``REPRO_FUSED_MAX_WINDOW_BYTES`` overrides both."""
+    env = os.environ.get("REPRO_FUSED_MAX_WINDOW_BYTES")
+    if env:
+        return int(env)
+    return fused_vmem_cap() if jax.default_backend() == "tpu" else 1 << 62
+
+
+def edge_ends_store(src, dst, klo: int, khi: int, block_edges: int):
+    """(src, dst) layout node ids per *edge storage* row — the fused
+    kernel's edge-window endpoint operand: owned-slot endpoints with the
+    dual store's ``klo*EB`` prefix / ``khi*EB`` suffix rows added as
+    (0, 0), an empty edge."""
+    ends = jnp.stack([jnp.asarray(src, jnp.int32).reshape(-1),
+                      jnp.asarray(dst, jnp.int32).reshape(-1)], axis=1)
+    return jnp.pad(ends, ((klo * block_edges, khi * block_edges), (0, 0)))
 
 
 @jax.tree_util.register_pytree_node_class
@@ -230,7 +308,8 @@ class EmpiricalGraph:
         data-dependent scatter on TPU).
         """
         gathered = u[self.inc_edges]                     # (V, max_deg, n)
-        return jnp.einsum("vd,vdn->vn", self.inc_signs, gathered)
+        return jnp.einsum("vd,vdn->vn", self.inc_signs, gathered,
+                          precision="highest")
 
     # -- TV seminorm (paper eq. 3) ------------------------------------------
     def total_variation(self, w: jnp.ndarray) -> jnp.ndarray:
@@ -317,8 +396,7 @@ def _plan_edge_blocks_fixed(graph: EmpiricalGraph, block_nodes: int,
     """Plan the edge-blocked layout for an explicit block size.
 
     ``min_extents`` forces lower bounds on the padded extents
-    (``num_blocks`` / ``block_edges`` / ``kn`` / ``klo`` / ``khi`` /
-    ``max_degree``): the hierarchical partitioner plans every shard's
+    (``num_blocks`` / ``block_edges`` / ``kn`` / ``klo`` / ``khi``): the hierarchical partitioner plans every shard's
     local subgraph twice and re-plans with the across-shard maxima so all
     shards share one static layout signature under ``shard_map``.
     Forced padding only widens windows and adds zero-weight slots — the
@@ -376,35 +454,26 @@ def _plan_edge_blocks_fixed(graph: EmpiricalGraph, block_nodes: int,
     edge_pos[eorder] = pos
     edge_flip = np.where(flip, -1.0, 1.0).astype(np.float32)
 
-    # 4. incidence tables over the padded layout nodes, in *owned* edge
-    #    positions for now (shifted to storage ids once klo is known).
-    #    Vectorized scatter: interleave (src, dst) endpoints so each
-    #    node's slots keep edge order, stable-sort by node, and the slot
-    #    column is the rank within the node's group.
-    max_deg = max(graph.max_degree, int(me.get("max_degree", 1)), 1)
-    inc_e = np.zeros((V_pad, max_deg), dtype=np.int64)
-    inc_s = np.zeros((V_pad, max_deg), dtype=np.float32)
+    # 4. per layout node, the first and last owned position of its
+    #    incident edges: sort the (src, dst) endpoints by node and reduce
+    #    each node's group
+    deg_counts = np.zeros(V_pad, np.int64)
+    node_emin = np.zeros(V_pad, np.int64)
+    node_emax = np.zeros(V_pad, np.int64)
     if E:
-        endpoints = np.empty(2 * E, dtype=np.int64)
-        endpoints[0::2], endpoints[1::2] = lo, hi
-        epos = np.repeat(pos, 2)
-        esign = np.tile(np.asarray([1.0, -1.0], np.float32), E)
+        endpoints = np.concatenate([lo, hi])
         order2 = np.argsort(endpoints, kind="stable")
-        nodes_sorted = endpoints[order2]
+        epos = np.concatenate([pos, pos])[order2]
         deg_counts = np.bincount(endpoints, minlength=V_pad)
         group_start = np.concatenate([[0], np.cumsum(deg_counts)])[:-1]
-        slot = np.arange(2 * E) - group_start[nodes_sorted]
-        inc_e[nodes_sorted, slot] = epos[order2]
-        inc_s[nodes_sorted, slot] = esign[order2]
-    fill = np.count_nonzero(inc_s, axis=1)
+        nz = deg_counts > 0
+        node_emin[nz] = np.minimum.reduceat(epos, group_start[nz])
+        node_emax[nz] = np.maximum.reduceat(epos, group_start[nz])
 
     # 5. halo extents.  Per block b the kernel needs (a) w rows for owned
     #    nodes and dst endpoints of owned edges, (b) u rows for every edge
     #    incident to those nodes.
-    has_inc = fill > 0
-    node_emin = np.where(has_inc, np.where(inc_s != 0, inc_e,
-                                           np.iinfo(np.int64).max).min(1), 0)
-    node_emax = np.where(has_inc, np.where(inc_s != 0, inc_e, -1).max(1), 0)
+    has_inc = deg_counts > 0
     kn = int(me.get("kn", 1))
     klo = int(me.get("klo", 0))
     khi = int(me.get("khi", 0))
@@ -422,19 +491,14 @@ def _plan_edge_blocks_fixed(graph: EmpiricalGraph, block_nodes: int,
             khi = max(khi, -(-(emax + 1 - (b + 1) * EB) // EB))
     klo, khi = max(klo, 0), max(khi, 0)
 
-    inc_e = inc_e + klo * EB               # owned position -> storage id
-
     return EdgeBlockLayout(
         block_nodes=BV, num_blocks=nb, block_edges=EB, kn=int(kn),
-        klo=int(klo), khi=int(khi), max_degree=max_deg, num_nodes=V,
-        num_edges=E,
+        klo=int(klo), khi=int(khi), num_nodes=V, num_edges=E,
         node_perm=jnp.asarray(node_perm, jnp.int32),
         node_inv=jnp.asarray(inv, jnp.int32),
         src=jnp.asarray(src_l, jnp.int32),
         dst=jnp.asarray(dst_l, jnp.int32),
         weights=jnp.asarray(w_l),
-        inc_edges=jnp.asarray(inc_e, jnp.int32),
-        inc_signs=jnp.asarray(inc_s),
         edge_pos=jnp.asarray(edge_pos, jnp.int32),
         edge_flip=jnp.asarray(edge_flip),
     )

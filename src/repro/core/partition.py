@@ -336,8 +336,8 @@ class HierarchyPlan:
     refresh, so second-ring staleness never propagates into owned state.
 
     All shards share one static extent signature (``block_nodes`` /
-    ``num_blocks`` / ``block_edges`` / ``kn`` / ``klo`` / ``khi`` /
-    ``max_degree``): the per-shard layouts are re-planned with the
+    ``num_blocks`` / ``block_edges`` / ``kn`` / ``klo`` / ``khi``): the
+    per-shard layouts are re-planned with the
     across-shard maxima forced, so a single ``shard_map`` trace serves
     every shard.  Stacked per-shard arrays have leading dimension
     ``S * rows`` and shard s occupies rows ``[s*rows, (s+1)*rows)``.
@@ -359,12 +359,9 @@ class HierarchyPlan:
     kn: int
     klo: int
     khi: int
-    max_degree: int
     # per-shard stacked arrays (host numpy)
     node_map: np.ndarray        # (S*NV,) layout row -> global node id (-1 pad)
     node_owned: np.ndarray      # (S*NV,) f32 1.0 where assign[node] == shard
-    inc_edges: np.ndarray       # (S*NV, max_degree) int32 storage edge ids
-    inc_signs: np.ndarray       # (S*NV, max_degree) f32 +1/-1/0
     src: np.ndarray             # (S*NE,) int32 layout node ids per owned slot
     dst: np.ndarray             # (S*NE,) int32
     weights: np.ndarray         # (S*NE,) f32 A_e (0 for padding slots)
@@ -503,23 +500,20 @@ def plan_hierarchy(graph: EmpiricalGraph, assign: np.ndarray,
         "kn": max(lt.kn for lt in pass2),
         "klo": max(lt.klo for lt in pass2),
         "khi": max(lt.khi for lt in pass2),
-        "max_degree": max(lt.max_degree for lt in pass2),
     }
     layouts = [lt if (lt.num_blocks, lt.block_edges, lt.kn, lt.klo,
-                      lt.khi, lt.max_degree) == tuple(me.values())
+                      lt.khi) == tuple(me.values())
                else plan_edge_blocks(lg, block_nodes=BV, min_extents=me)
                for lt, (_, _, lg) in zip(pass2, locals_)]
 
     nb, EB = me["num_blocks"], me["block_edges"]
-    kn, klo, khi, md = me["kn"], me["klo"], me["khi"], me["max_degree"]
+    kn, klo, khi = me["kn"], me["klo"], me["khi"]
     NV, NE = nb * BV, nb * EB
     WSR = (nb + kn - 1) * BV
     ESR = (nb + klo + khi) * EB
 
     node_map = np.full(S * NV, -1, np.int64)
     node_owned = np.zeros(S * NV, np.float32)
-    inc_e = np.zeros((S * NV, md), np.int32)
-    inc_s = np.zeros((S * NV, md), np.float32)
     src_l = np.zeros(S * NE, np.int32)
     dst_l = np.zeros(S * NE, np.int32)
     w_l = np.zeros(S * NE, np.float32)
@@ -537,8 +531,6 @@ def plan_hierarchy(graph: EmpiricalGraph, assign: np.ndarray,
         node_owned[s * NV:(s + 1) * NV] = np.where(
             valid & (assign[np.clip(nm, 0, max(V - 1, 0))] == s)
             if V else valid, 1.0, 0.0)
-        inc_e[s * NV:(s + 1) * NV] = np.asarray(lt.inc_edges, np.int32)
-        inc_s[s * NV:(s + 1) * NV] = np.asarray(lt.inc_signs, np.float32)
         src_l[s * NE:(s + 1) * NE] = np.asarray(lt.src, np.int32)
         dst_l[s * NE:(s + 1) * NE] = np.asarray(lt.dst, np.int32)
         w_l[s * NE:(s + 1) * NE] = np.asarray(lt.weights, np.float32)
@@ -614,9 +606,8 @@ def plan_hierarchy(graph: EmpiricalGraph, assign: np.ndarray,
     return HierarchyPlan(
         num_shards=S, num_nodes=V, num_edges=E,
         block_nodes=BV, num_blocks=nb, block_edges=EB, kn=kn, klo=klo,
-        khi=khi, max_degree=md,
-        node_map=node_map, node_owned=node_owned, inc_edges=inc_e,
-        inc_signs=inc_s, src=src_l, dst=dst_l, weights=w_l,
+        khi=khi,
+        node_map=node_map, node_owned=node_owned, src=src_l, dst=dst_l, weights=w_l,
         edge_map=edge_map, edge_owned=edge_owned, orient=orient,
         send_rows=NS, send_idx=send_idx, send_flip=send_flip,
         recv_src=recv_src, recv_src_dense=recv_src_dense,

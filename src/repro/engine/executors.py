@@ -50,6 +50,32 @@ class DenseExecutor:
         return u
 
 
+def _bf16_parts(x: jnp.ndarray) -> tuple:
+    """Three bf16 arrays whose f32 sum is exactly ``x`` (8 + 8 + 8 of
+    f32's 24 significand bits)."""
+    parts = []
+    for _ in range(3):
+        p = x.astype(jnp.bfloat16)
+        parts.append(p)
+        x = x - p.astype(jnp.float32)
+    return tuple(parts)
+
+
+def _exact_dot(a: jnp.ndarray, x: jnp.ndarray, contract: tuple):
+    """f32-exact contraction of a bf16-exact matrix ``a`` (entries 0/+-1)
+    with an f32 ``x``: three single-pass bf16 MXU products, f32
+    accumulation.  The TPU's default f32 matmul rounds ``x`` to bf16,
+    and ``Precision.HIGHEST`` spends six passes (and a far longer Mosaic
+    compile) on what three exact ones give."""
+    dims = (contract, ((), ()))
+    out = None
+    for part in _bf16_parts(x):
+        y = jax.lax.dot_general(a, part, dims,
+                                preferred_element_type=jnp.float32)
+        out = y if out is None else out + y
+    return out
+
+
 @dataclasses.dataclass(frozen=True)
 class WindowExecutor:
     """One VMEM window of the edge-blocked layout (``EdgeBlockLayout``).
@@ -57,39 +83,81 @@ class WindowExecutor:
     State shapes differ from the dense case: ``w`` is the (NW, n) node
     window (owned + halo blocks), the gather-side dual state is the
     (EW, n) edge window, and the executor *owns* the (EB, n) rows at
-    offset ``klo * EB`` inside it.  ``inc_local`` holds window-relative
-    edge ids (pre-clipped), ``src_local`` / ``dst_local`` window-relative
-    node ids per owned edge.  ``weights`` carries the already
+    offset ``klo * EB`` inside it.  ``weights`` carries the already
     lambda-scaled clip levels ``lam * A_e`` for the owned edges (the
     kernel precomputes them once per solve), so the canonical step is
     invoked with ``lam = 1.0``.
 
+    D and D^T read the window-relative (src, dst) node ids ``ends`` of
+    the (EW,) window edges.  Padding slots have src == dst and zero
+    duals.  Endpoints outside the node window are dropped, so an edge
+    crossing the window edge contributes only to its in-window endpoint;
+    the layout guarantees every edge incident to an owned or halo node
+    lies in the edge window, so those rows of D^T u are exact.
+
+    Inside a compiled TPU kernel (``mxu=True``) Mosaic refuses row
+    gathers and scatters by an index array, so both products there run
+    as contractions with the window's signed incidence matrix (EW, NW)
+    (+1 at the src column, -1 at the dst column), built once per window
+    from ``ends`` with iota compares and held in bf16, which represents
+    its 0/+-1 entries exactly; the products run on the MXU at f32
+    precision (:func:`_exact_dot`).  Everywhere else (the jnp reference
+    and interpret mode, which run the same step) they are the O(EW)
+    segment sum and row gather: the contraction costs O(EW * NW), which
+    a CPU pays in full.
+
     Precision policy: the window adapter (``kernels.ref.pd_window_step``)
-    upcasts a reduced-storage (bf16) window to f32 *before* building this
-    executor's state, so every gather-sum and incidence reduction here
-    accumulates in f32 regardless of what dtype the state was stored in.
+    upcasts a reduced-storage (bf16) window to f32 *before* calling the
+    step, so every contraction here accumulates in f32 regardless of
+    what dtype the state was stored in.
     """
 
-    inc_local: jnp.ndarray      # (NW, max_deg) window-relative edge ids
-    inc_signs: jnp.ndarray      # (NW, max_deg) +1 / -1 / 0
-    src_local: jnp.ndarray      # (EB,) window-relative src node ids
-    dst_local: jnp.ndarray      # (EB,) window-relative dst node ids
+    ends: jnp.ndarray           # (EW, 2) window-relative (src, dst) ids
+    num_nodes: int              # NW
     weights: jnp.ndarray        # (EB, 1) lam * A_e per owned edge
     klo: int
     block_edges: int
+    incidence: jnp.ndarray | None = None    # (EW, NW) bf16 when mxu
+
+    @classmethod
+    def from_endpoints(cls, ends: jnp.ndarray, num_nodes: int,
+                       weights: jnp.ndarray, *, klo: int,
+                       block_edges: int, mxu: bool = False
+                       ) -> "WindowExecutor":
+        """Build the executor from (EW, 2) window-relative (src, dst)
+        node ids of the window's edges; ``mxu`` builds the incidence
+        matrix the contractions use."""
+        incidence = None
+        if mxu:
+            col = jax.lax.broadcasted_iota(
+                jnp.int32, (ends.shape[0], num_nodes), 1)
+            f32 = jnp.float32
+            incidence = ((col == ends[:, 0:1]).astype(f32)
+                         - (col == ends[:, 1:2]).astype(f32)
+                         ).astype(jnp.bfloat16)
+        return cls(ends=ends, num_nodes=num_nodes, weights=weights,
+                   klo=klo, block_edges=block_edges, incidence=incidence)
+
+    def _owned_rows(self, a: jnp.ndarray) -> jnp.ndarray:
+        eb = self.block_edges
+        return jax.lax.slice_in_dim(a, self.klo * eb, (self.klo + 1) * eb)
 
     def gather_duals(self, u: jnp.ndarray) -> jnp.ndarray:
-        n = u.shape[1]
-        gathered = u[self.inc_local.reshape(-1)].reshape(
-            self.inc_local.shape + (n,))             # (NW, max_deg, n)
-        return jnp.einsum("vd,vdn->vn", self.inc_signs, gathered)
+        if self.incidence is not None:
+            return _exact_dot(self.incidence, u, ((0,), (0,)))
+        nw = self.num_nodes
+        return (jax.ops.segment_sum(u, self.ends[:, 0], nw)
+                - jax.ops.segment_sum(u, self.ends[:, 1], nw))
 
     def edge_diff(self, z: jnp.ndarray) -> jnp.ndarray:
-        return z[self.src_local] - z[self.dst_local]
+        if self.incidence is not None:
+            return _exact_dot(self._owned_rows(self.incidence), z,
+                              ((1,), (0,)))
+        ends = self._owned_rows(self.ends)
+        return z[ends[:, 0]] - z[ends[:, 1]]
 
     def owned_duals(self, u: jnp.ndarray) -> jnp.ndarray:
-        eb = self.block_edges
-        return jax.lax.slice_in_dim(u, self.klo * eb, (self.klo + 1) * eb)
+        return self._owned_rows(u)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -291,7 +359,8 @@ class MailboxExecutor:
         g = self.graph
         gathered = jnp.where(self.pos_signs, u[g.inc_edges],
                              self.u_recv[g.inc_edges])
-        return jnp.einsum("vd,vdn->vn", g.inc_signs, gathered)
+        return jnp.einsum("vd,vdn->vn", g.inc_signs, gathered,
+                          precision="highest")
 
     def edge_diff(self, z: jnp.ndarray) -> jnp.ndarray:
         with _scope(_prof.PHASE_MAILBOX_DIFF):

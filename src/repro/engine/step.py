@@ -151,10 +151,10 @@ def certificate(problem, w: jnp.ndarray, u: jnp.ndarray) -> dict:
         u, problem.graph, problem.lam)}
     if isinstance(problem.loss, SquaredLoss):
         data = problem.data
-        pred = jnp.einsum("vmn,vn->vm", data.x, w)
+        pred = jnp.einsum("vmn,vn->vm", data.x, w, precision="highest")
         r = (pred - data.y) * data.sample_mask
-        grad = 2.0 * jnp.einsum("vm,vmn->vn", r,
-                                data.x) / data.counts()[:, None]
+        grad = 2.0 * jnp.einsum("vm,vmn->vn", r, data.x,
+                                precision="highest") / data.counts()[:, None]
         grad = grad * data.labeled_mask[:, None]
         station = grad + (problem.graph.incidence_transpose_apply(u)
                           * data.labeled_mask[:, None])
@@ -180,7 +180,8 @@ def optimality_gap(problem, w: jnp.ndarray, u: jnp.ndarray) -> jnp.ndarray:
     bounded by the data, and at convergence the iterate is the
     minimizer, so the margin holds) — which keeps every per-node min
     finite even for singular node covariances.  Labeled nodes solve the
-    regularized normal equations via pinv and correct for curvature
+    regularized normal equations via the pseudo-inverse
+    (:func:`psd_pinv_solve`) and correct for curvature
     null-space components with the first-order ball bound
     ``min >= f(w*) - 2R |grad f(w*)|``; unlabeled nodes are exact:
     ``-R |z_i|``.  Weak duality gives ``P(w) - P* <= gap`` for every
@@ -194,19 +195,38 @@ def optimality_gap(problem, w: jnp.ndarray, u: jnp.ndarray) -> jnp.ndarray:
     z = problem.graph.incidence_transpose_apply(u_feas)        # (V, n)
     cnt = data.counts()[:, None]
     xm = data.x * data.sample_mask[..., None]
-    q = jnp.einsum("vmn,vmk->vnk", xm, data.x) / cnt[..., None]
-    c = jnp.einsum("vmn,vm->vn", xm, data.y) / cnt
+    q = jnp.einsum("vmn,vmk->vnk", xm, data.x,
+                   precision="highest") / cnt[..., None]
+    c = jnp.einsum("vmn,vm->vn", xm, data.y, precision="highest") / cnt
     yty = jnp.sum(data.y ** 2 * data.sample_mask, axis=1) / cnt[:, 0]
     radius = 2.0 * jnp.max(jnp.linalg.norm(w, axis=1)) + 1.0
 
     rhs = c - 0.5 * z
-    w_star = jnp.einsum("vnk,vk->vn", jnp.linalg.pinv(q), rhs)
-    lval = (jnp.einsum("vn,vnk,vk->v", w_star, q, w_star)
+    w_star = psd_pinv_solve(q, rhs)
+    lval = (jnp.einsum("vn,vnk,vk->v", w_star, q, w_star, precision="highest")
             - 2.0 * jnp.sum(c * w_star, axis=1) + yty)
     # grad of f(w) = ell(w) + z^T w at w*: 2 (Q w* - rhs)
-    grad = 2.0 * (jnp.einsum("vnk,vk->vn", q, w_star) - rhs)
+    grad = 2.0 * (jnp.einsum("vnk,vk->vn", q, w_star, precision="highest")
+                  - rhs)
     g_lab = (lval + jnp.sum(z * w_star, axis=1)
              - 2.0 * radius * jnp.linalg.norm(grad, axis=1))
     g_unl = -radius * jnp.linalg.norm(z, axis=1)
     g = jnp.sum(jnp.where(data.labeled_mask > 0, g_lab, g_unl))
     return (problem.objective(w) - g).astype(jnp.float32)
+
+
+def psd_pinv_solve(q: jnp.ndarray, rhs: jnp.ndarray) -> jnp.ndarray:
+    """``pinv(q_i) @ rhs_i`` for a batch of symmetric PSD matrices.
+
+    Through the eigendecomposition, with ``jnp.linalg.pinv``'s cutoff
+    (eigenvalues at or below ``10 n eps`` times the largest are dropped).
+    ``jnp.linalg.pinv`` itself takes an SVD, which the TPU compiler
+    cannot fit in its scoped VMEM for 250k or more batched 2x2 matrices.
+    """
+    s, v = jnp.linalg.eigh(q)                      # q = v diag(s) v^T
+    cutoff = (10 * q.shape[-1] * jnp.finfo(q.dtype).eps
+              * jnp.max(jnp.abs(s), axis=-1, keepdims=True))
+    keep = jnp.abs(s) > cutoff
+    inv_s = jnp.where(keep, 1.0 / jnp.where(keep, s, 1.0), 0.0)
+    coef = jnp.sum(v * rhs[..., :, None], axis=-2) * inv_s     # v^T rhs
+    return jnp.sum(v * coef[..., None, :], axis=-1)

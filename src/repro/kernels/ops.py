@@ -2,9 +2,10 @@
 
 Each op dispatches to the Pallas kernel on TPU and to ``interpret=True``
 (or the jnp reference for speed, where noted) elsewhere, so the same call
-sites work in CPU tests and on real hardware.  Set
-``REPRO_FORCE_INTERPRET=1`` to force interpret mode everywhere (used by the
-kernel test-suite).
+sites work in CPU tests and on real hardware.  Off-TPU,
+``REPRO_FORCE_INTERPRET=1`` runs the kernels in interpret mode instead of
+the references (used by the kernel test-suite); on a TPU it has no
+effect.
 """
 from __future__ import annotations
 
@@ -24,8 +25,8 @@ def _on_tpu() -> bool:
 
 
 def _interpret() -> bool:
-    if os.environ.get("REPRO_FORCE_INTERPRET"):
-        return True
+    """Interpret mode off-TPU only: on a TPU the kernels always compile
+    (``REPRO_FORCE_INTERPRET`` cannot move them off the chip)."""
     return not _on_tpu()
 
 
@@ -33,8 +34,8 @@ def _use_kernel_default() -> bool:
     """Kernel path on TPU; jnp reference elsewhere (interpret-mode Pallas
     on CPU is orders of magnitude slower than the XLA reference, which is
     what the CI conformance matrix would otherwise pay on every solve).
-    ``REPRO_FORCE_INTERPRET=1`` forces the kernels everywhere (the kernel
-    test-suite and the recorded perf baselines use this)."""
+    ``REPRO_FORCE_INTERPRET=1`` runs the kernels (in interpret mode)
+    off-TPU too."""
     return _on_tpu() or bool(os.environ.get("REPRO_FORCE_INTERPRET"))
 
 
@@ -66,9 +67,9 @@ def batched_affine(p: jnp.ndarray, v: jnp.ndarray, *,
     return _ref.batched_affine_ref(p, v).astype(v.dtype)
 
 
-def pd_step(w_store, u_store, inc_edges, inc_signs, params, tau, src, dst,
-            sigma, la, *, loss, reg, pkeys, block_nodes, block_edges, kn,
-            klo, khi, rho=1.0, iters=1, compute_residual=False,
+def pd_step(w_store, u_store, ends, params, tau, sigma, la, *, loss, reg,
+            pkeys, block_nodes, block_edges, kn, klo, khi, rho=1.0,
+            iters=1, compute_residual=False,
             use_kernel: bool | None = None):
     """Fused primal-dual step over an edge-blocked layout (Algorithm 1
     body in one pass): Pallas kernel on TPU, the bit-comparable jnp
@@ -85,5 +86,4 @@ def pd_step(w_store, u_store, inc_edges, inc_signs, params, tau, src, dst,
               iters=iters, compute_residual=compute_residual)
     if use_kernel:
         kw["interpret"] = _interpret()
-    return fn(w_store, u_store, inc_edges, inc_signs, params, tau, src,
-              dst, sigma, la, **kw)
+    return fn(w_store, u_store, ends, params, tau, sigma, la, **kw)

@@ -21,7 +21,13 @@ leaves, sorted by key) each getting its own windowed BlockSpec, and the
 in-kernel body is ``kernels.ref.pd_window_step`` — which itself runs the
 canonical ``repro.engine.step.pd_step`` through a window executor.  The
 iteration math is therefore stated once in the engine; this kernel is
-locked to it by the interpret-mode bit-parity tests.
+locked to it by the interpret-mode bit-parity tests.  Compiled for a
+TPU, D and D^T run as MXU contractions with the window's signed
+incidence matrix, built from the window edges' endpoints by iota
+compares (``engine.executors.WindowExecutor``): Mosaic refuses row
+gathers by an index array, and the window is small enough to hold the
+matrix.  In interpret mode they are the reference's segment sum and
+row gather.
 
 Layout contract (all index maps are plain ``i + j`` offsets because the
 layout pass aligns every block's halo window to exactly ``i * BV`` /
@@ -29,7 +35,8 @@ layout pass aligns every block's halo window to exactly ``i * BV`` /
 
   * node storage rows:  ``nb*BV`` owned + ``(kn-1)*BV`` suffix padding,
   * edge storage rows:  ``klo*EB`` prefix + ``nb*EB`` owned + ``khi*EB``
-    suffix padding (incidence tables hold *storage* ids),
+    suffix padding; the (src, dst) endpoint store ``ends`` shares these
+    rows (layout node ids; padding rows have src == dst),
   * per grid step ``i``: node window = ``kn`` consecutive BV-blocks from
     ``i``, edge window = ``klo+1+khi`` consecutive EB-blocks from ``i``.
 
@@ -48,13 +55,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.graph import fused_vmem_cap
 from repro.kernels import ref as _ref
 
 
 def _make_kernel(bv: int, eb: int, kn: int, ktot: int, klo: int,
                  num_params: int, loss, reg, pkeys: tuple, rho: float,
-                 iters: int, compute_residual: bool):
+                 iters: int, compute_residual: bool, mxu: bool):
     """Build the grid-step kernel for fixed layout extents."""
 
     def cat(refs):
@@ -66,34 +75,29 @@ def _make_kernel(bv: int, eb: int, kn: int, ktot: int, klo: int,
         pos = 0
         w_refs = refs[pos:pos + kn]; pos += kn
         u_refs = refs[pos:pos + ktot]; pos += ktot
-        ie_refs = refs[pos:pos + kn]; pos += kn
-        is_refs = refs[pos:pos + kn]; pos += kn
+        end_refs = refs[pos:pos + ktot]; pos += ktot
         param_refs = [refs[pos + p * kn:pos + (p + 1) * kn]
                       for p in range(num_params)]
         pos += num_params * kn
         tau_refs = refs[pos:pos + kn]; pos += kn
-        src_ref, dst_ref, sig_ref, la_ref = refs[pos:pos + 4]; pos += 4
+        sig_ref, la_ref = refs[pos:pos + 2]; pos += 2
         w_out_ref, u_out_ref = refs[pos:pos + 2]; pos += 2
         res_ref = refs[pos] if compute_residual else None
 
         i = pl.program_id(0)
         w_win = cat(w_refs)                      # (NW, n)
         u_win = cat(u_refs)                      # (EW, n)
-        nw, ew = w_win.shape[0], u_win.shape[0]
-        # storage ids -> window-local (clipped; sign 0 kills stray slots)
-        el = jnp.clip(cat(ie_refs) - i * eb, 0, ew - 1)
-        isg = cat(is_refs)
         params_win = tuple(cat(prefs) for prefs in param_refs)
         tau_win = cat(tau_refs)
-        sl = jnp.clip(src_ref[...][:, 0] - i * bv, 0, nw - 1)
-        dl = jnp.clip(dst_ref[...][:, 0] - i * bv, 0, nw - 1)
-        sg, bd = sig_ref[...], la_ref[...]
+        sg = sig_ref[...]
+        ex = _ref.window_executor(cat(end_refs), w_win.shape[0], i * bv,
+                                  la_ref[...], klo=klo, block_edges=eb,
+                                  mxu=mxu)
 
         def one(w, u):
-            return _ref.pd_window_step(w, u, el, isg, params_win, tau_win,
-                                       sl, dl, sg, bd, loss=loss, reg=reg,
-                                       pkeys=pkeys, klo=klo,
-                                       block_edges=eb, rho=rho)
+            return _ref.pd_window_step(ex, w, u, params_win, tau_win, sg,
+                                       loss=loss, reg=reg, pkeys=pkeys,
+                                       rho=rho)
 
         if iters == 1:
             w_o, u_o = one(w_win, u_win)
@@ -142,11 +146,9 @@ def _make_kernel(bv: int, eb: int, kn: int, ktot: int, klo: int,
     "loss", "reg", "pkeys", "block_nodes", "block_edges", "kn", "klo",
     "khi", "rho", "iters", "compute_residual", "interpret"))
 def fused_pd_step(w_store: jnp.ndarray, u_store: jnp.ndarray,
-                  inc_edges: jnp.ndarray, inc_signs: jnp.ndarray,
-                  params: tuple, tau: jnp.ndarray,
-                  src: jnp.ndarray, dst: jnp.ndarray, sigma: jnp.ndarray,
-                  la: jnp.ndarray, *, loss, reg, pkeys: tuple,
-                  block_nodes: int, block_edges: int,
+                  ends: jnp.ndarray, params: tuple, tau: jnp.ndarray,
+                  sigma: jnp.ndarray, la: jnp.ndarray, *, loss, reg,
+                  pkeys: tuple, block_nodes: int, block_edges: int,
                   kn: int, klo: int, khi: int, rho: float = 1.0,
                   iters: int = 1, compute_residual: bool = False,
                   interpret: bool = False):
@@ -155,14 +157,18 @@ def fused_pd_step(w_store: jnp.ndarray, u_store: jnp.ndarray,
     u_new (nb*EB, n)); with ``compute_residual`` also the f32 scalar
     eq.-11 residual of the call (max over blocks, and over iterations
     when ``iters > 1``), computed in-kernel so a tol solve never reads
-    the state back to form its stopping criterion."""
+    the state back to form its stopping criterion.
+
+    The kernel may claim the fused VMEM cap
+    (:func:`repro.core.graph.fused_vmem_cap`); the routers only hand it
+    layouts whose window estimate fits under it
+    (``EdgeBlockLayout.window_bytes``)."""
     bv, eb = block_nodes, block_edges
     ktot = klo + 1 + khi
-    nb = src.shape[0] // eb
+    nb = sigma.shape[0] // eb
     if iters != 1 and nb != 1:
         raise ValueError("multi-iteration fusion requires a single block")
     n = w_store.shape[1]
-    max_deg = inc_edges.shape[1]
     params = tuple(params)
 
     def nmap(j, rank=2):
@@ -175,33 +181,36 @@ def fused_pd_step(w_store: jnp.ndarray, u_store: jnp.ndarray,
     in_specs = (
         [pl.BlockSpec((bv, n), nmap(j)) for j in range(kn)]          # w views
         + [pl.BlockSpec((eb, n), nmap(j)) for j in range(ktot)]      # u views
-        + [pl.BlockSpec((bv, max_deg), nmap(j)) for j in range(kn)]  # inc ids
-        + [pl.BlockSpec((bv, max_deg), nmap(j)) for j in range(kn)]  # inc sign
+        + [pl.BlockSpec((eb, 2), nmap(j)) for j in range(ktot)]      # ends
         + param_specs                                                # prox
         + [pl.BlockSpec((bv, 1), nmap(j)) for j in range(kn)]        # tau
-        + [pl.BlockSpec((eb, 1), nmap(0))] * 4                       # src/dst/sig/la
+        + [pl.BlockSpec((eb, 1), nmap(0))] * 2                       # sig/la
     )
     out_specs = [pl.BlockSpec((bv, n), nmap(0)),
                  pl.BlockSpec((eb, n), nmap(0))]
     out_shape = [jax.ShapeDtypeStruct((nb * bv, n), w_store.dtype),
                  jax.ShapeDtypeStruct((nb * eb, n), u_store.dtype)]
     if compute_residual:
-        out_specs.append(pl.BlockSpec((1, 1), nmap(0)))
-        out_shape.append(jax.ShapeDtypeStruct((nb, 1), jnp.float32))
+        # one (1, 1) tile per grid step: the block's minor dims equal the
+        # array's, which is what the TPU lowering requires of them
+        out_specs.append(pl.BlockSpec((None, 1, 1), nmap(0, 3)))
+        out_shape.append(jax.ShapeDtypeStruct((nb, 1, 1), jnp.float32))
 
     operands = (
-        [w_store] * kn + [u_store] * ktot + [inc_edges] * kn
-        + [inc_signs] * kn
+        [w_store] * kn + [u_store] * ktot + [ends] * ktot
         + [leaf for leaf in params for _ in range(kn)]
-        + [tau] * kn + [src, dst, sigma, la]
+        + [tau] * kn + [sigma, la]
     )
     outs = pl.pallas_call(
         _make_kernel(bv, eb, kn, ktot, klo, len(params), loss, reg,
-                     pkeys, rho, iters, compute_residual),
+                     pkeys, rho, iters, compute_residual,
+                     mxu=not interpret),
         grid=(nb,),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=fused_vmem_cap()),
         interpret=interpret,
     )(*operands)
     if compute_residual:

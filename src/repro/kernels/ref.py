@@ -33,47 +33,52 @@ def batched_affine_ref(p: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
 
     p: (V, n, n); v: (V, n) (already includes the +b_i shift).
     """
-    return jnp.einsum("vnk,vk->vn", p, v)
+    return jnp.einsum("vnk,vk->vn", p, v, precision="highest")
 
 
-def pd_window_step(w_win: jnp.ndarray, u_win: jnp.ndarray,
-                   inc_local: jnp.ndarray, inc_signs: jnp.ndarray,
-                   params_win: tuple, tau_win: jnp.ndarray,
-                   src_local: jnp.ndarray, dst_local: jnp.ndarray,
-                   sigma: jnp.ndarray, la: jnp.ndarray, *, loss, reg,
-                   pkeys: tuple, klo: int, block_edges: int,
-                   rho: float = 1.0):
+def window_executor(ends_win: jnp.ndarray, num_nodes: int,
+                    node_offset, la: jnp.ndarray, *, klo: int,
+                    block_edges: int, mxu: bool = False) -> WindowExecutor:
+    """The window executor of one grid step: storage endpoints of the
+    (EW,) window edges made window-relative by ``node_offset`` (the
+    window's first node row).  Built once per window — its incidence
+    matrix (``mxu``, a compiled TPU kernel) is loop-invariant across
+    fused iterations."""
+    return WindowExecutor.from_endpoints(
+        ends_win - node_offset, num_nodes, la, klo=klo,
+        block_edges=block_edges, mxu=mxu)
+
+
+def pd_window_step(executor: WindowExecutor, w_win: jnp.ndarray,
+                   u_win: jnp.ndarray, params_win: tuple,
+                   tau_win: jnp.ndarray, sigma: jnp.ndarray, *, loss, reg,
+                   pkeys: tuple, rho: float = 1.0):
     """One fused primal-dual step on a single VMEM-resident window.
 
-    A thin adapter: builds the window executor and the windowed prox,
-    then runs the canonical engine step.  The Pallas kernel
+    A thin adapter: builds the windowed prox, then runs the canonical
+    engine step through the window ``executor``.  The Pallas kernel
     (kernels/pd_step.py) runs exactly this function on its loaded
     window, so interpret-mode kernel output is bit-comparable to the jnp
     reference (:func:`fused_pd_step_ref`).
 
     Precision policy: ``w_win`` / ``u_win`` and the prox parameter
     windows may arrive in a reduced *storage* dtype (bf16) — HBM<->VMEM
-    traffic then moves half the bytes — while the gather-sums, prox
+    traffic then moves half the bytes — while the contractions, prox
     solves, and dual resolvent always *accumulate* in f32: the window is
     upcast on entry and the outputs are cast back to the storage dtype.
     f32 storage is the identity path (bitwise unchanged).
 
     Window shapes (see ``core.graph.EdgeBlockLayout``): ``w_win`` (NW, n),
-    ``u_win`` (EW, n), ``inc_local`` / ``inc_signs`` (NW, max_deg) with
-    edge ids already relative to the window (pre-clipped), ``params_win``
-    a tuple of per-node prox parameter windows (leaves (NW, ...), keyed
-    by the static ``pkeys`` — the sorted keys of ``loss.prox_setup``),
-    ``tau_win`` (NW, 1), and per *owned* edge ``src_local`` /
-    ``dst_local`` (EB,), ``sigma`` / ``la`` (EB, 1) with ``la`` the
-    pre-scaled ``lam * A_e`` (the canonical step runs at ``lam = 1``).
+    ``u_win`` (EW, n), ``params_win`` a tuple of per-node prox parameter
+    windows (leaves (NW, ...), keyed by the static ``pkeys`` — the
+    sorted keys of ``loss.prox_setup``), ``tau_win`` (NW, 1), and per
+    *owned* edge ``sigma`` (EB, 1); the executor carries the pre-scaled
+    clip levels ``lam * A_e`` (the canonical step runs at ``lam = 1``).
     Returns (w_relaxed_window (NW, n), u_new_owned (EB, n)) in the
     storage dtype.
     """
     store = w_win.dtype
     f32 = jnp.float32
-    executor = WindowExecutor(
-        inc_local=inc_local, inc_signs=inc_signs, src_local=src_local,
-        dst_local=dst_local, weights=la, klo=klo, block_edges=block_edges)
     params = dict(zip(
         pkeys,
         (p.astype(f32) if jnp.issubdtype(p.dtype, jnp.floating) else p
@@ -109,9 +114,7 @@ def window_residual(w_old: jnp.ndarray, u_old: jnp.ndarray,
 
 
 def fused_pd_step_ref(w_store: jnp.ndarray, u_store: jnp.ndarray,
-                      inc_edges: jnp.ndarray, inc_signs: jnp.ndarray,
-                      params: tuple, tau: jnp.ndarray,
-                      src: jnp.ndarray, dst: jnp.ndarray,
+                      ends: jnp.ndarray, params: tuple, tau: jnp.ndarray,
                       sigma: jnp.ndarray, la: jnp.ndarray, *, loss, reg,
                       pkeys: tuple, block_nodes: int, block_edges: int,
                       kn: int, klo: int, khi: int, rho: float = 1.0,
@@ -120,8 +123,10 @@ def fused_pd_step_ref(w_store: jnp.ndarray, u_store: jnp.ndarray,
 
     Storage shapes (layout order, see ``EdgeBlockLayout``):
       w_store (nb*BV + (kn-1)*BV, n), u_store ((nb+klo+khi)*EB, n),
-      inc_edges/inc_signs/tau and every ``params`` leaf padded to the
-      same node-store rows, src/dst/sigma/la (nb*EB, 1).
+      ``ends`` ((nb+klo+khi)*EB, 2) int32 layout node ids (src, dst) of
+      the edge in each u_store row (``core.graph.edge_ends_store``),
+      tau and every ``params`` leaf padded to the w_store rows,
+      sigma/la (nb*EB, 1) per owned edge.
     Returns (w_new (nb*BV, n), u_new (nb*EB, n)).  ``iters > 1`` (the
     whole-graph-in-VMEM multi-iteration fusion) requires nb == 1.
 
@@ -138,13 +143,11 @@ def fused_pd_step_ref(w_store: jnp.ndarray, u_store: jnp.ndarray,
     rounding is per iteration by construction.
     """
     bv, eb = block_nodes, block_edges
-    nb = src.shape[0] // eb
+    nb = sigma.shape[0] // eb
     if iters != 1 and nb != 1:
         raise ValueError("multi-iteration fusion requires a single block")
     n = w_store.shape[1]
     nw, ew = kn * bv, (klo + 1 + khi) * eb
-    max_deg = inc_edges.shape[1]
-
     def node_slice(a, n0):
         return jax.lax.dynamic_slice(
             a, (n0,) + (0,) * (a.ndim - 1), (nw,) + a.shape[1:])
@@ -153,8 +156,6 @@ def fused_pd_step_ref(w_store: jnp.ndarray, u_store: jnp.ndarray,
         n0, e0 = i * bv, i * eb
         w_win = jax.lax.dynamic_slice(w_store, (n0, 0), (nw, n))
         u_win = jax.lax.dynamic_slice(u_store, (e0, 0), (ew, n))
-        ie = jax.lax.dynamic_slice(inc_edges, (n0, 0), (nw, max_deg))
-        isg = jax.lax.dynamic_slice(inc_signs, (n0, 0), (nw, max_deg))
         # prox parameters are read-only across iterations: upcast a bf16
         # store once here instead of per pd_window_step call (the cast
         # inside is then a no-op) — identical values, ~params/state fewer
@@ -164,19 +165,16 @@ def fused_pd_step_ref(w_store: jnp.ndarray, u_store: jnp.ndarray,
             if jnp.issubdtype(a.dtype, jnp.floating) else a
             for a in (node_slice(a, n0) for a in params))
         tau_win = jax.lax.dynamic_slice(tau, (n0, 0), (nw, 1))
-        sv = jax.lax.dynamic_slice(src, (e0, 0), (eb, 1))[:, 0]
-        dv = jax.lax.dynamic_slice(dst, (e0, 0), (eb, 1))[:, 0]
         sg = jax.lax.dynamic_slice(sigma, (e0, 0), (eb, 1))
         bd = jax.lax.dynamic_slice(la, (e0, 0), (eb, 1))
-        el = jnp.clip(ie - e0, 0, ew - 1)
-        sl = jnp.clip(sv - n0, 0, nw - 1)
-        dl = jnp.clip(dv - n0, 0, nw - 1)
+        ex = window_executor(
+            jax.lax.dynamic_slice(ends, (e0, 0), (ew, 2)), nw, n0, bd,
+            klo=klo, block_edges=eb)
 
         def one(w_win_, u_win_):
-            return pd_window_step(w_win_, u_win_, el, isg, params_win,
-                                  tau_win, sl, dl, sg, bd, loss=loss,
-                                  reg=reg, pkeys=pkeys, klo=klo,
-                                  block_edges=eb, rho=rho)
+            return pd_window_step(ex, w_win_, u_win_, params_win, tau_win,
+                                  sg, loss=loss, reg=reg, pkeys=pkeys,
+                                  rho=rho)
 
         u_owned_lo = klo * eb
         if iters == 1:

@@ -25,8 +25,8 @@ def _ridge_kernel(p_ref, v_ref, o_ref):
     v = v_ref[...]                # (BLOCK_V, n)
     # batched matvec: contract the last axis of p with v
     o_ref[...] = jnp.einsum("bnk,bk->bn", p, v,
-                            preferred_element_type=jnp.float32).astype(
-                                o_ref.dtype)
+                            preferred_element_type=jnp.float32,
+                            precision="highest").astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_v", "interpret"))

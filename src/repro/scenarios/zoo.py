@@ -81,7 +81,14 @@ def chain_changepoint(rng: np.random.Generator,
     graph_family="grid", data_model="piecewise-constant regression",
     lam=5e-2, lam_path=(5e-3, 2e-2, 5e-2, 2e-1), metric="mse")
 def grid2d(rng: np.random.Generator, smoke: bool) -> NetworkedDataset:
-    side = 8 if smoke else 20
+    return lattice_dataset(rng, 8 if smoke else 20)
+
+
+def lattice_dataset(rng: np.random.Generator,
+                    side: int) -> NetworkedDataset:
+    """``grid2d``'s data model on a ``side`` x ``side`` lattice (n = 2
+    features, m = 5 samples per node, a fifth of the nodes labeled) —
+    also the chip-scale lattice of ``chip_smoke.py``."""
     graph = grid_graph(rng, side, side)
     rr, cc = np.meshgrid(np.arange(side), np.arange(side), indexing="ij")
     quad = ((rr >= side // 2).astype(np.int64) * 2

@@ -104,9 +104,11 @@ class Plan:
 # EdgeBlockLayout field split for (de)serialization: python ints vs the
 # device arrays that go through repro.checkpoint.
 _LAYOUT_STATIC = ("block_nodes", "num_blocks", "block_edges", "kn", "klo",
-                  "khi", "max_degree", "num_nodes", "num_edges")
+                  "khi", "num_nodes", "num_edges")
 _LAYOUT_ARRAYS = ("node_perm", "node_inv", "src", "dst", "weights",
-                  "inc_edges", "inc_signs", "edge_pos", "edge_flip")
+                  "edge_pos", "edge_flip")
+# plans.json format; 2 dropped the layouts' per-node incidence tables
+_PLANS_VERSION = 2
 
 
 def _payload_hash(arrays: "OrderedDict[str, np.ndarray]") -> str:
@@ -301,7 +303,7 @@ class PlanCache:
 
         ckpt.save(path, trees)
         with open(os.path.join(path, "plans.json"), "w") as f:
-            json.dump({"version": 1, "plans": plan_metas,
+            json.dump({"version": _PLANS_VERSION, "plans": plan_metas,
                        "rcm_orders": rcm_metas}, f, indent=1, sort_keys=True)
         return {"plans": len(plan_metas), "rcm_orders": len(rcm_metas)}
 
@@ -321,6 +323,10 @@ class PlanCache:
 
         with open(os.path.join(path, "plans.json")) as f:
             meta = json.load(f)
+        if meta.get("version") != _PLANS_VERSION:
+            raise ValueError(
+                f"plan checkpoint {path} has format version "
+                f"{meta.get('version')}; this build reads {_PLANS_VERSION}")
 
         like: dict[str, dict[str, np.ndarray]] = {}
         for entry in meta["plans"]:

@@ -34,10 +34,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.api.backends import _should_fuse
+from repro.api.backends import _graph_layout, _should_fuse
 from repro.api.problem import Problem, SolverConfig
 from repro.api.solver import Solver, solve_path as _solve_path
-from repro.core.graph import build_graph, plan_edge_blocks
+from repro.core.graph import build_graph
 from repro.core.partition import transfer_edge_duals
 from repro.engine import capped as _capped
 from repro.serving.cache import Plan, PlanCache, PlanKey
@@ -260,9 +260,8 @@ class SolveService:
             if (config.backend == "pallas"
                     and _should_fuse(problem, config)
                     and problem.graph.num_edges):
-                layout = (problem.graph.layout
-                          if problem.graph.layout is not None
-                          else plan_edge_blocks(problem.graph))
+                # the layout the fusion gate sized against the VMEM cap
+                layout = _graph_layout(problem.graph)
             return Plan(key=key, layout=layout)
 
         return self.plans.get_or_build(key, build, sig=sig)
@@ -406,8 +405,8 @@ class SolveService:
                     if result.residual is not None else float("nan"))
         certificate = {k: float(v)
                        for k, v in result.diagnostics.items()
-                       if k != "iterations" and not k.startswith("halo_")
-                       and np.ndim(v) == 0}
+                       if k not in ("iterations", "route")
+                       and not k.startswith("halo_") and np.ndim(v) == 0}
         resp = SolveResponse(
             session_id=sess.session_id,
             w=result.w,

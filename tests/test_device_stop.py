@@ -90,9 +90,9 @@ def test_tol_none_never_syncs_per_chunk(monkeypatch):
 
 def _manual_residual(args, kw, w_new, u_new):
     """eq.-11 residual over owned rows, straight from kernel in/out."""
-    w_store, u_store, tau, sigma = args[0], args[1], args[5], args[8]
+    w_store, u_store, tau, sigma = args[0], args[1], args[4], args[5]
     eb, klo = kw["block_edges"], kw["klo"]
-    nb = args[6].shape[0] // eb
+    nb = sigma.shape[0] // eb
     bv = kw["block_nodes"]
     f32 = np.float32
     w0 = np.asarray(w_store, f32)[:nb * bv]

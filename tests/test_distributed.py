@@ -141,15 +141,17 @@ def test_hierarchy_halo_closure_covers_owned_incidence(ds, hier4):
     src, dst = np.asarray(g.src), np.asarray(g.dst)
     np.add.at(dtu, src, u)
     np.add.at(dtu, dst, -u)
-    NV, ESR = h.nodes_pad, h.u_store_rows
+    NV, NE, ESR = h.nodes_pad, h.edges_pad, h.u_store_rows
     u_store = np.zeros((h.u_inj.shape[0], 2), np.float32)
     valid = h.u_inj >= 0
     u_store[valid] = u[h.u_inj[valid]] * h.u_inj_flip[valid, None]
     for s in range(h.num_shards):
-        inc_e = h.inc_edges[s * NV:(s + 1) * NV]
-        inc_s = h.inc_signs[s * NV:(s + 1) * NV]
-        ust = u_store[s * ESR:(s + 1) * ESR]
-        contrib = (ust[inc_e] * inc_s[:, :, None]).sum(axis=1)
+        rows = slice(s * NE, (s + 1) * NE)
+        real = h.weights[rows] > 0
+        ust = u_store[s * ESR + h.klo * h.block_edges:][:NE][real]
+        contrib = np.zeros((NV, 2), np.float32)
+        np.add.at(contrib, h.src[rows][real], ust)
+        np.add.at(contrib, h.dst[rows][real], -ust)
         own = h.node_owned[s * NV:(s + 1) * NV] > 0
         gids = h.node_map[s * NV:(s + 1) * NV][own]
         np.testing.assert_allclose(contrib[own], dtu[gids], atol=1e-5)
@@ -251,3 +253,59 @@ def test_hierarchical_determinism_across_shard_counts(ds):
     # the small-graph fixture has a low cut fraction: comm="auto" must
     # have picked boundary exchange at low shard counts
     assert out["comms"][0] == "boundary", out
+
+
+DEFAULT_MESH_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import json
+    from repro.api import Problem, Solver, SolverConfig
+    from repro.core.mesh import make_device_mesh, make_host_mesh
+    from repro.data.synthetic import make_sbm_regression
+
+    ds = make_sbm_regression(seed=3, cluster_sizes=(24, 24), p_in=0.5,
+                             p_out=5e-3, num_labeled=12)
+    prob = Problem.create(ds.graph, ds.data, 1e-3)
+    out = {"mesh_devices": int(make_device_mesh().devices.size)}
+    for backend in ("sharded", "sharded_fused"):
+        cfg = SolverConfig(backend=backend, num_iters=20, comm="dense")
+        implicit = Solver(cfg).run(prob).diagnostics
+        explicit = Solver(cfg.replace(mesh=make_host_mesh(4, 1))).run(
+            prob).diagnostics
+        one = Solver(cfg.replace(mesh=make_host_mesh(1, 1))).run(
+            prob).diagnostics
+        key = "halo_exchange_bytes_per_iter"
+        out[backend] = [implicit[key], explicit[key], one[key]]
+    print(json.dumps(out))
+""")
+
+
+def test_sharded_backends_default_to_every_device():
+    """With no mesh in the config, both sharded backends spread the
+    graph over every device of the process (4 virtual devices here):
+    the exchange volume is the explicit 4-device mesh's, not one
+    device's."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.abspath(
+        os.path.join(os.path.dirname(__file__), "..", "src"))
+    res = subprocess.run([sys.executable, "-c", DEFAULT_MESH_SCRIPT],
+                         capture_output=True, text=True, env=env,
+                         timeout=600)
+    assert res.returncode == 0, res.stderr[-2000:]
+    out = json.loads(res.stdout.strip().splitlines()[-1])
+    assert out["mesh_devices"] == 4
+    for backend in ("sharded", "sharded_fused"):
+        implicit, explicit, one = out[backend]
+        assert implicit == explicit != one, (backend, out[backend])
+
+
+def test_sharded_fused_refuses_a_window_over_the_vmem_cap(ds, monkeypatch):
+    """A per-shard layout whose fused window exceeds the VMEM cap is an
+    error naming both numbers, never a kernel the chip would refuse."""
+    from repro.api import Problem, Solver, SolverConfig
+    monkeypatch.setenv("REPRO_FUSED_MAX_WINDOW_BYTES", "4096")
+    prob = Problem.create(ds.graph, ds.data, 1e-3)
+    with pytest.raises(ValueError, match=r"needs \d+ bytes of VMEM.*cap "
+                                         r"is 4096 bytes"):
+        Solver(SolverConfig(backend="sharded_fused", num_iters=10,
+                            mesh=make_host_mesh(1, 1))).run(prob)

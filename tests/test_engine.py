@@ -22,7 +22,7 @@ from repro.api import Solver, SolverConfig
 from repro.api.backends import _should_fuse
 from repro.api.losses import SquaredLoss
 from repro.api.regularizers import TotalVariation
-from repro.core.graph import plan_edge_blocks, sbm_graph
+from repro.core.graph import edge_ends_store, plan_edge_blocks, sbm_graph
 from repro.core.mesh import make_host_mesh
 from repro.data.synthetic import make_sbm_regression
 from repro.engine import DenseExecutor, MailboxExecutor, WindowExecutor
@@ -37,7 +37,8 @@ def _whole_graph_window(v=48, n=2, seed=3):
     g, _ = sbm_graph(rng, (v // 2, v - v // 2), p_in=0.4, p_out=0.05)
     lt = plan_edge_blocks(g)                  # small graph -> one block
     assert lt.num_blocks == 1 and lt.kn == 1 and lt.klo == lt.khi == 0
-    deg = jnp.sum(lt.inc_signs != 0.0, axis=1).astype(jnp.float32)
+    real = (lt.weights > 0.0).astype(jnp.float32)
+    deg = jnp.zeros(lt.nodes_pad).at[lt.src].add(real).at[lt.dst].add(real)
     tau = jnp.where(deg > 0, 1.0 / jnp.maximum(deg, 1.0), 1.0)[:, None]
     w = jnp.asarray(rng.standard_normal((lt.nodes_pad, n)), jnp.float32)
     u = jnp.asarray(0.1 * rng.standard_normal((lt.edges_pad, n)),
@@ -60,10 +61,9 @@ def test_pallas_kernel_is_bitwise_the_engine_step(rho):
     lt, _, w, u, p, b, tau, sigma, la = _whole_graph_window()
     loss, reg = SquaredLoss(), TotalVariation()
 
-    executor = WindowExecutor(
-        inc_local=lt.inc_edges, inc_signs=lt.inc_signs, src_local=lt.src,
-        dst_local=lt.dst, weights=la, klo=0,
-        block_edges=lt.block_edges)
+    ends = edge_ends_store(lt.src, lt.dst, 0, 0, lt.block_edges)
+    executor = WindowExecutor.from_endpoints(
+        ends, lt.nodes_pad, la, klo=0, block_edges=lt.block_edges)
     params = {"b": b, "p": p}
 
     def prox(v):
@@ -72,20 +72,50 @@ def test_pallas_kernel_is_bitwise_the_engine_step(rho):
     w_eng, u_eng = pd_step(executor, prox, reg, 1.0, tau, sigma, w, u,
                            rho=rho)
     w_k, u_k = fused_pd_step(
-        w, u, lt.inc_edges, lt.inc_signs, (b, p), tau, lt.src[:, None],
-        lt.dst[:, None], sigma, la, loss=loss, reg=reg, pkeys=("b", "p"),
-        block_nodes=lt.block_nodes, block_edges=lt.block_edges, kn=1,
-        klo=0, khi=0, rho=rho, interpret=True)
+        w, u, ends, (b, p), tau, sigma, la, loss=loss, reg=reg,
+        pkeys=("b", "p"), block_nodes=lt.block_nodes,
+        block_edges=lt.block_edges, kn=1, klo=0, khi=0, rho=rho,
+        interpret=True)
     # the kernel body IS engine.pd_step (same Python function on the
-    # loaded window); XLA may fuse the gather-sum einsum differently
-    # inside the interpreted kernel, so parity is exact up to 1 ulp of
-    # the contraction — assert that, plus that almost all entries are
-    # bit-identical.
+    # loaded window); XLA may fuse the incidence contractions
+    # differently inside the interpreted kernel, so parity is exact up
+    # to 1 ulp of the contraction — assert that, plus that almost all
+    # entries are bit-identical.
     assert float(jnp.max(jnp.abs(w_k - w_eng))) <= 1e-6
     assert float(jnp.max(jnp.abs(u_k - u_eng))) <= 1e-6
     w_same = np.mean(np.asarray(w_k) == np.asarray(w_eng))
     u_same = np.mean(np.asarray(u_k) == np.asarray(u_eng))
     assert w_same >= 0.5 and u_same >= 0.5, (w_same, u_same)
+
+
+@pytest.mark.parametrize("bv", [16, 512])
+def test_window_executor_contraction_form_matches_gather_form(bv):
+    """The incidence contractions a compiled TPU kernel runs
+    (``mxu=True``) compute the same D^T u and D z, per window, as the
+    segment-sum / row-gather form of the reference and interpret mode,
+    to f32 rounding; ``bv=16`` gives multi-block windows with halos."""
+    rng = np.random.default_rng(11)
+    g, _ = sbm_graph(rng, (60, 60), p_in=0.3, p_out=0.03)
+    lt = plan_edge_blocks(g, block_nodes=bv)
+    BV, EB = lt.block_nodes, lt.block_edges
+    nw, ew = lt.kn * BV, (lt.klo + 1 + lt.khi) * EB
+    ends = edge_ends_store(lt.src, lt.dst, lt.klo, lt.khi, EB)
+    u = jnp.asarray(rng.standard_normal((ends.shape[0], 3)), jnp.float32)
+    u = jnp.where((ends[:, 0] == ends[:, 1])[:, None], 0.0, u)  # pad: 0
+    z = jnp.asarray(rng.standard_normal((nw, 3)), jnp.float32)
+    la = jnp.ones((EB, 1), jnp.float32)
+    for b in range(lt.num_blocks):
+        win = ends[b * EB:b * EB + ew] - b * BV
+        forms = [WindowExecutor.from_endpoints(
+            win, nw, la, klo=lt.klo, block_edges=EB, mxu=mxu)
+            for mxu in (False, True)]
+        u_win = u[b * EB:b * EB + ew]
+        np.testing.assert_allclose(forms[1].gather_duals(u_win),
+                                   forms[0].gather_duals(u_win),
+                                   rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(forms[1].edge_diff(z),
+                                   forms[0].edge_diff(z),
+                                   rtol=1e-6, atol=1e-6)
 
 
 def test_mailbox_executor_equals_dense_executor_when_synced():
@@ -356,6 +386,23 @@ def test_optimality_gap_upper_bounds_suboptimality():
         assert gap >= -1e-6, (iters, gap)
         gaps.append(gap)
     assert gaps[-1] < gaps[0], gaps
+
+
+def test_psd_pinv_solve_matches_pinv():
+    """The certificate's eigendecomposition pseudo-inverse agrees with
+    ``jnp.linalg.pinv`` on full-rank, rank-deficient and zero PSD
+    blocks."""
+    from repro.engine.step import psd_pinv_solve
+    rng = np.random.default_rng(0)
+    x = rng.standard_normal((64, 5, 3)).astype(np.float32)
+    x[8:16, :, 2] = x[8:16, :, 0]                 # rank 2
+    x[16:24] = 0.0                                # zero blocks
+    q = jnp.asarray(np.einsum("vmn,vmk->vnk", x, x) / 5.0)
+    rhs = jnp.asarray(rng.standard_normal((64, 3)).astype(np.float32))
+    want = jnp.einsum("vnk,vk->vn", jnp.linalg.pinv(q), rhs)
+    got = psd_pinv_solve(q, rhs)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-3, atol=1e-3)
 
 
 def test_certificate_reports_optimality_gap_column():

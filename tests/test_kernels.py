@@ -73,14 +73,15 @@ def test_batched_affine_matches_ref(v, n, dtype):
 def _fused_step_args(v, n, bv, seed=0, rho=1.9):
     from repro.api.losses import SquaredLoss
     from repro.api.regularizers import TotalVariation
-    from repro.core.graph import plan_edge_blocks, sbm_graph
+    from repro.core.graph import edge_ends_store, plan_edge_blocks, sbm_graph
     rng = np.random.default_rng(seed)
     g, _ = sbm_graph(rng, (v // 2, v - v // 2), p_in=0.3, p_out=0.03)
     lt = plan_edge_blocks(g, block_nodes=bv)
     kk = jax.random.split(jax.random.PRNGKey(seed), 4)
     ext = (lt.kn - 1) * lt.block_nodes
     pad = lambda a: jnp.pad(a, ((0, ext),) + ((0, 0),) * (a.ndim - 1))
-    deg = jnp.sum(lt.inc_signs != 0.0, axis=1).astype(jnp.float32)
+    real = (lt.weights > 0.0).astype(jnp.float32)
+    deg = jnp.zeros(lt.nodes_pad).at[lt.src].add(real).at[lt.dst].add(real)
     tau = jnp.where(deg > 0, 1.0 / jnp.maximum(deg, 1.0), 1.0)
     # squared-loss prox params (P, b) in sorted-pkeys order ("b", "p")
     p_win = pad(rnd(kk[2], (lt.nodes_pad, n, n), scale=0.1)
@@ -91,9 +92,9 @@ def _fused_step_args(v, n, bv, seed=0, rho=1.9):
         jnp.pad(rnd(kk[1], (lt.edges_pad, n), scale=0.1),
                 ((lt.klo * lt.block_edges, lt.khi * lt.block_edges),
                  (0, 0))),
-        pad(lt.inc_edges), pad(lt.inc_signs),
+        edge_ends_store(lt.src, lt.dst, lt.klo, lt.khi, lt.block_edges),
         (b_win, p_win),
-        pad(tau[:, None]), lt.src[:, None], lt.dst[:, None],
+        pad(tau[:, None]),
         jnp.full((lt.edges_pad, 1), 0.5),
         (1e-2 * lt.weights)[:, None],
     )
@@ -129,6 +130,16 @@ def test_fused_pd_step_multi_iteration_equals_repeated_single():
                                rtol=1e-6, atol=1e-6)
     np.testing.assert_allclose(np.asarray(u_m), np.asarray(u),
                                rtol=1e-6, atol=1e-6)
+
+
+def test_force_interpret_has_no_effect_on_tpu(monkeypatch):
+    """``REPRO_FORCE_INTERPRET`` moves kernels into interpret mode off-TPU
+    only: on a TPU they always compile for the chip."""
+    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "1")
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    assert ops._use_kernel_default() and not ops._interpret()
+    monkeypatch.setattr(ops, "_on_tpu", lambda: False)
+    assert ops._use_kernel_default() and ops._interpret()
 
 
 # ---------------------------------------------------------------------------

@@ -57,14 +57,13 @@ def test_layout_structure(v, bv):
     assert np.all(dst[real] < owner[real] * BV + lt.kn * BV)
     # halo guarantee (b): every incident edge of owned + halo nodes inside
     # the edge window of the owning block (storage ids, window start b*EB)
-    inc_e = np.asarray(lt.inc_edges)
-    inc_s = np.asarray(lt.inc_signs)
+    store = np.arange(lt.edges_pad) + lt.klo * EB   # owned slot -> storage
     ew = (lt.klo + 1 + lt.khi) * EB
     for b in range(nb):
         own = np.arange(b * BV, (b + 1) * BV)
         halo = dst[b * EB:(b + 1) * EB][real[b * EB:(b + 1) * EB]]
         nodes = np.unique(np.concatenate([own, halo]))
-        e = inc_e[nodes][inc_s[nodes] != 0]
+        e = store[real & (np.isin(src, nodes) | np.isin(dst, nodes))]
         if len(e):
             assert e.min() >= b * EB and e.max() < b * EB + ew, b
 
@@ -203,3 +202,22 @@ def test_fused_warm_start_and_continuation_match_dense():
     f1 = Solver(cfgf).run(problem, w0=f0.w, u0=f0.u)
     assert float(jnp.max(jnp.abs(d1.w - f1.w))) <= 1e-4
     assert float(jnp.max(jnp.abs(d1.u - f1.u))) <= 1e-4
+
+
+def test_pallas_route_is_reported(monkeypatch):
+    """``diagnostics["route"]`` says which kernels ran: the fused window
+    with its extents and VMEM estimate, or — when the estimate exceeds
+    the cap — the unfused route, with the numbers that decided it."""
+    problem = make_problem(103, seed=3)
+    cfg = CFG.replace(backend="pallas", fused=True, num_iters=20,
+                      metric_every=10)
+    route = Solver(cfg).run(problem).diagnostics["route"]
+    assert route["fused"] and route["window_bytes"] <= route["window_cap"]
+    assert route["num_blocks"] >= 1 and route["kn"] >= 1
+    monkeypatch.setenv("REPRO_FUSED_MAX_WINDOW_BYTES", "4096")
+    problem = make_problem(103, seed=4)       # a fresh graph: re-planned
+    route = Solver(cfg).run(problem).diagnostics["route"]
+    assert not route["fused"]
+    assert route["window_bytes"] > route["window_cap"] == 4096
+    assert Solver(cfg.replace(fused=False)).run(
+        problem).diagnostics["route"] == {"fused": False}
