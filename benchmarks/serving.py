@@ -190,7 +190,7 @@ def run(seed: int = 0, verbose: bool = True,
     sid = svc.create_session("tenant_a", problem)
 
     # session admission: the first solve pays plan build + XLA compile
-    # (the response attributes it: compile_seconds = seconds - execute)
+    # (the response counts JAX's compile events inside it)
     first = svc.solve(sid)
     compile_seconds = first.compile_seconds
 

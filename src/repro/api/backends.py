@@ -59,6 +59,7 @@ from repro.engine import (DenseExecutor, certificate, device_loop,
                           pd_residual, scan_solve)
 from repro.engine import pd_step as engine_pd_step
 from repro.kernels import ops
+from repro import obs
 from repro.obs import device_fetch
 
 BACKENDS: dict[str, Callable] = {}
@@ -121,10 +122,14 @@ def pd_iteration(graph, prox: Callable, regularizer: Regularizer, lam,
 
 
 def _diagnostics(problem: Problem, w, u, config: SolverConfig) -> dict:
-    """Certificate per config — empty for throwaway (warm-phase) solves."""
+    """Certificate per config — empty for throwaway (warm-phase) solves.
+
+    The ``certificate`` span holds the eager certificate's dispatch; its
+    device time lands where its values are first read."""
     if not config.compute_diagnostics:
         return {}
-    return certificate(problem, w, u)
+    with obs.span("certificate"):
+        return certificate(problem, w, u)
 
 
 def _check_cadence(config: SolverConfig) -> None:
@@ -306,43 +311,54 @@ _dense_tol = _jit(_dense_tol_impl,
 
 def _solve_dense(problem: Problem, config: SolverConfig, *, w0=None, u0=None,
                  w_true=None, clip_fn=None, affine_fn=None) -> SolveResult:
+    """Dense-engine driver.  Its spans: ``solver_setup`` (initial state,
+    prox set-up), ``solver_engine`` (see :func:`_solve_fused`) and, on
+    the tol path, ``solver_unpack`` (the trace buffers cut to the
+    stopping block)."""
     _check_cadence(config)
     _storage_dtype(config, fused=False)
-    V, n = problem.num_nodes, problem.num_features
-    if w0 is None:
-        w0 = jnp.zeros((V, n), jnp.float32)
-    if u0 is None:
-        u0 = jnp.zeros((problem.graph.num_edges, n), jnp.float32)
-    if config.tol is None or config.num_iters == 0:
-        # a 0-iteration budget degenerates to the (0-length) scan; the
-        # chunk loop would have no chunks and hence no traces to return
-        w, u, obj, mse, res = _dense_scan(
-            problem.graph, problem.data, problem.lam, w0, u0, w_true,
-            loss=problem.loss, reg=problem.regularizer,
-            num_iters=config.num_iters, rho=config.rho,
-            metric_every=config.metric_every, clip_fn=clip_fn,
-            affine_fn=affine_fn,
-            record_residual=config.record_residual)
-        iterations = config.num_iters
-    else:
-        # per-solve prox setup happens once, not once per block
-        try:
-            params = problem.loss.prox_setup(
-                problem.data, problem.graph.primal_stepsizes())
-        except NotImplementedError:
-            params = None
-        w, u, obj, mse, res, its = _dense_tol(
-            problem.graph, problem.data, problem.lam, w0, u0, w_true,
-            params, config.tol, loss=problem.loss,
-            reg=problem.regularizer, num_iters=config.num_iters,
-            rho=config.rho, metric_every=config.metric_every,
-            clip_fn=clip_fn, affine_fn=affine_fn)
-        # the solve's single device->host transfer: the stopping
-        # iteration; the trace buffers truncate lazily from it
-        (iterations,) = device_fetch((its,))
-        iterations = int(iterations)
-        nb = iterations // config.metric_every
-        obj, mse, res = obj[:nb], mse[:nb], res[:nb]
+    # a 0-iteration budget degenerates to the (0-length) scan; the chunk
+    # loop would have no chunks and hence no traces to return
+    fixed = config.tol is None or config.num_iters == 0
+    with obs.span("solver_setup"):
+        V, n = problem.num_nodes, problem.num_features
+        if w0 is None:
+            w0 = jnp.zeros((V, n), jnp.float32)
+        if u0 is None:
+            u0 = jnp.zeros((problem.graph.num_edges, n), jnp.float32)
+        params = None
+        if not fixed:
+            # per-solve prox setup happens once, not once per block
+            try:
+                params = problem.loss.prox_setup(
+                    problem.data, problem.graph.primal_stepsizes())
+            except NotImplementedError:
+                pass
+    with obs.span("solver_engine"):
+        if fixed:
+            w, u, obj, mse, res = _dense_scan(
+                problem.graph, problem.data, problem.lam, w0, u0, w_true,
+                loss=problem.loss, reg=problem.regularizer,
+                num_iters=config.num_iters, rho=config.rho,
+                metric_every=config.metric_every, clip_fn=clip_fn,
+                affine_fn=affine_fn,
+                record_residual=config.record_residual)
+            iterations = config.num_iters
+        else:
+            w, u, obj, mse, res, its = _dense_tol(
+                problem.graph, problem.data, problem.lam, w0, u0, w_true,
+                params, config.tol, loss=problem.loss,
+                reg=problem.regularizer, num_iters=config.num_iters,
+                rho=config.rho, metric_every=config.metric_every,
+                clip_fn=clip_fn, affine_fn=affine_fn)
+            # the solve's single device->host transfer: the stopping
+            # iteration; the trace buffers truncate lazily from it
+            (iterations,) = device_fetch((its,))
+            iterations = int(iterations)
+    if not fixed:
+        with obs.span("solver_unpack"):
+            nb = iterations // config.metric_every
+            obj, mse, res = obj[:nb], mse[:nb], res[:nb]
     diag = _with_iterations(_diagnostics(problem, w, u, config), config,
                             iterations)
     return SolveResult(w=w, u=u, objective=obj,
@@ -821,71 +837,82 @@ _fused_tol = _jit(_fused_tol_impl,
 
 def _solve_fused(problem: Problem, config: SolverConfig, *, w0=None,
                  u0=None, w_true=None) -> SolveResult:
-    """Solve via the fused PD kernel on the edge-blocked graph layout."""
+    """Solve via the fused PD kernel on the edge-blocked graph layout.
+
+    Its spans: ``solver_setup`` (layout gathers, ``_fused_setup``, the
+    padded stores), ``solver_engine`` and ``solver_unpack`` (the trace
+    buffers cut to the stopping block, ``w`` and ``u`` un-permuted).
+    On the tol path ``solver_engine`` runs through the fetch of the
+    stopping iteration, a sync, so the device time of the set-up and of
+    the loop lands inside it; on the fixed-iteration path it holds the
+    scan's dispatch only.
+    """
     _check_cadence(config)
     dtype = _storage_dtype(config, fused=True)
     store_dt = jnp.dtype(dtype)
     lt = _graph_layout(problem.graph)
     n = problem.num_features
     data = problem.data
-
-    def gather_nodes(a):
-        return gather_padded(a, lt.node_perm)
-
-    if w0 is None:
-        w0_l = jnp.zeros((lt.nodes_pad, n), jnp.float32)
-    else:
-        w0_l = gather_nodes(jnp.asarray(w0, jnp.float32))
-    u0_l = jnp.zeros((lt.edges_pad, n), jnp.float32)
-    if u0 is not None:
-        u0_l = u0_l.at[lt.edge_pos].set(
-            jnp.asarray(u0, jnp.float32) * lt.edge_flip[:, None])
-
+    # 0-iteration budget: degenerate 0-length scan, no while loop
+    fixed = config.tol is None or config.num_iters == 0
     layout_arrays = (lt.node_perm, lt.node_inv, lt.src, lt.dst, lt.weights,
                      lt.edge_pos)
     use_kernel = ops._use_kernel_default()
-    if config.tol is None or config.num_iters == 0:
-        # 0-iteration budget: degenerate 0-length scan, no while loop
-        w_l, u_l, obj, mse, res = _fused_scan(
-            problem.graph, data, w0_l, u0_l, problem.lam, w_true,
-            layout_arrays, loss=problem.loss,
-            reg=problem.regularizer, layout=lt,
-            num_iters=config.num_iters, rho=config.rho,
-            metric_every=config.metric_every, use_kernel=use_kernel,
-            record_residual=config.record_residual, dtype=dtype)
-        iterations = config.num_iters
-    else:
-        # per-solve setup (layout gathers, prox params, padded
-        # stepsizes) runs once, eagerly; the while loop advances the
-        # padded stores in the storage dtype
-        (params_s, pkeys, tau_l, tau_s, sig_l, sig2, ends, la2,
-         _metrics) = _fused_setup(
-            problem.graph, data, problem.lam, w_true, layout_arrays,
-            loss=problem.loss, reg=problem.regularizer, layout=lt,
-            dtype=dtype)
-        eb, klo = lt.block_edges, lt.klo
-        store0 = (lt.pad_node_store(w0_l).astype(store_dt),
-                  jnp.pad(u0_l, ((klo * eb, lt.khi * eb),
-                                 (0, 0))).astype(store_dt))
-        w_store, u_store, obj, mse, res, its = _fused_tol(
-            problem.graph, data, store0[0], store0[1], problem.lam,
-            w_true, lt.node_inv, ends, params_s, tau_s, sig2, la2,
-            config.tol, loss=problem.loss,
-            reg=problem.regularizer, layout=lt, pkeys=pkeys,
-            num_iters=config.num_iters, rho=config.rho,
-            metric_every=config.metric_every, use_kernel=use_kernel)
-        # the solve's single device->host transfer: the stopping
-        # iteration; the trace buffers truncate lazily from it
-        (iterations,) = device_fetch((its,))
-        iterations = int(iterations)
-        nb = iterations // config.metric_every
-        obj, mse, res = obj[:nb], mse[:nb], res[:nb]
-        w_l = jax.lax.slice_in_dim(w_store, 0, lt.nodes_pad)
-        u_l = jax.lax.slice_in_dim(u_store, klo * eb,
-                                   klo * eb + lt.edges_pad)
-    w = jnp.take(w_l, lt.node_inv, axis=0).astype(jnp.float32)
-    u = (jnp.take(u_l, lt.edge_pos, axis=0)
-         * lt.edge_flip[:, None]).astype(jnp.float32)
+
+    with obs.span("solver_setup"):
+        if w0 is None:
+            w0_l = jnp.zeros((lt.nodes_pad, n), jnp.float32)
+        else:
+            w0_l = gather_padded(jnp.asarray(w0, jnp.float32), lt.node_perm)
+        u0_l = jnp.zeros((lt.edges_pad, n), jnp.float32)
+        if u0 is not None:
+            u0_l = u0_l.at[lt.edge_pos].set(
+                jnp.asarray(u0, jnp.float32) * lt.edge_flip[:, None])
+        if not fixed:
+            # per-solve setup (layout gathers, prox params, padded
+            # stepsizes) runs once, eagerly; the while loop advances the
+            # padded stores in the storage dtype
+            (params_s, pkeys, tau_l, tau_s, sig_l, sig2, ends, la2,
+             _metrics) = _fused_setup(
+                problem.graph, data, problem.lam, w_true, layout_arrays,
+                loss=problem.loss, reg=problem.regularizer, layout=lt,
+                dtype=dtype)
+            eb, klo = lt.block_edges, lt.klo
+            store0 = (lt.pad_node_store(w0_l).astype(store_dt),
+                      jnp.pad(u0_l, ((klo * eb, lt.khi * eb),
+                                     (0, 0))).astype(store_dt))
+    with obs.span("solver_engine"):
+        if fixed:
+            w_l, u_l, obj, mse, res = _fused_scan(
+                problem.graph, data, w0_l, u0_l, problem.lam, w_true,
+                layout_arrays, loss=problem.loss,
+                reg=problem.regularizer, layout=lt,
+                num_iters=config.num_iters, rho=config.rho,
+                metric_every=config.metric_every, use_kernel=use_kernel,
+                record_residual=config.record_residual, dtype=dtype)
+            iterations = config.num_iters
+        else:
+            w_store, u_store, obj, mse, res, its = _fused_tol(
+                problem.graph, data, store0[0], store0[1], problem.lam,
+                w_true, lt.node_inv, ends, params_s, tau_s, sig2, la2,
+                config.tol, loss=problem.loss,
+                reg=problem.regularizer, layout=lt, pkeys=pkeys,
+                num_iters=config.num_iters, rho=config.rho,
+                metric_every=config.metric_every, use_kernel=use_kernel)
+            # the solve's single device->host transfer: the stopping
+            # iteration; the trace buffers truncate lazily from it
+            (iterations,) = device_fetch((its,))
+            iterations = int(iterations)
+    with obs.span("solver_unpack"):
+        if not fixed:
+            nb = iterations // config.metric_every
+            obj, mse, res = obj[:nb], mse[:nb], res[:nb]
+            w_l = jax.lax.slice_in_dim(w_store, 0, lt.nodes_pad)
+            u_l = jax.lax.slice_in_dim(u_store, klo * eb,
+                                       klo * eb + lt.edges_pad)
+        w = jnp.take(w_l, lt.node_inv, axis=0).astype(jnp.float32)
+        u = (jnp.take(u_l, lt.edge_pos, axis=0)
+             * lt.edge_flip[:, None]).astype(jnp.float32)
     diag = _with_iterations(_diagnostics(problem, w, u, config), config,
                             iterations)
     return SolveResult(w=w, u=u, objective=obj,
@@ -1038,8 +1065,6 @@ def _with_halo_traffic(diag, bytes_per_iter: int, iterations: int,
                        comm: str, backend: str):
     """Surface inter-shard exchange volume per solve (and mirror it onto
     the obs registry, CommLedger.export_obs-style)."""
-    from repro import obs
-
     total = int(bytes_per_iter) * int(iterations)
     diag = dict(diag or {})
     diag["halo_exchange_bytes_per_iter"] = float(bytes_per_iter)

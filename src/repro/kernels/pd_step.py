@@ -212,6 +212,7 @@ def fused_pd_step(w_store: jnp.ndarray, u_store: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=fused_vmem_cap()),
         interpret=interpret,
+        name="fused_pd_step",
     )(*operands)
     if compute_residual:
         w_new, u_new, res = outs

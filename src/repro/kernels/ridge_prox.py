@@ -50,5 +50,6 @@ def batched_affine(p: jnp.ndarray, v: jnp.ndarray, *,
         out_specs=pl.BlockSpec((block_v, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((v_pad, n), v.dtype),
         interpret=interpret,
+        name="batched_affine",
     )(p, v)
     return out[:vcount]

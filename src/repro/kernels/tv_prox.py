@@ -47,5 +47,6 @@ def tv_prox(u: jnp.ndarray, bound: jnp.ndarray, *,
         out_specs=pl.BlockSpec((block_e, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((e_pad, n), u.dtype),
         interpret=interpret,
+        name="tv_prox",
     )(u, b2)
     return out[:e]

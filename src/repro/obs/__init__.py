@@ -8,27 +8,31 @@ Public surface::
     obs.enable()                        # or REPRO_OBS=1 in the env
     obs.events.attach("events.jsonl")   # stream one event per request
 
-    with obs.span("my_phase"):          # host-side timing
+    with obs.span("my_phase"):          # repro.my_phase in a profile
         ...
     obs.counter("my_total").inc()
+    count, seconds = obs.compiles()     # JAX compiles so far
 
     print(obs.export.prometheus_text())  # or obs.export.export_json()
 
-Everything defaults **off** and costs nothing while off: see
+The registry defaults **off**; spans are the profiler's own
+annotations, which do nothing unless a profile is being recorded: see
 ``telemetry.py`` for the zero-overhead contract, ``events.py`` for the
 request event log, ``export.py`` for JSON/Prometheus snapshots, and
 ``profile.py`` for device-profile phase annotation.
 """
 from repro.obs import events, export, profile, telemetry
-from repro.obs.telemetry import (COUNT_BUCKETS, NULL_SPAN, REGISTRY,
-                                 SECONDS_BUCKETS, Counter, Gauge,
-                                 Histogram, counter, device_fetch,
-                                 disable, enable, enabled, gauge,
-                                 histogram, span)
+from repro.obs.telemetry import (COUNT_BUCKETS, REGISTRY,
+                                 SECONDS_BUCKETS, SPAN_PREFIX, Counter,
+                                 Gauge, Histogram, SpanRecord, compiles,
+                                 counter, device_fetch, disable, enable,
+                                 enabled, gauge, histogram,
+                                 profiled_spans, span)
 
 
 def reset() -> None:
-    """Clear all metrics and the event log (test isolation)."""
+    """Clear all metrics, the profiled spans and the event log (test
+    isolation)."""
     telemetry.reset()
     events.reset()
 
@@ -38,9 +42,11 @@ __all__ = [
     "Counter",
     "Gauge",
     "Histogram",
-    "NULL_SPAN",
     "REGISTRY",
     "SECONDS_BUCKETS",
+    "SPAN_PREFIX",
+    "SpanRecord",
+    "compiles",
     "counter",
     "device_fetch",
     "disable",
@@ -51,6 +57,7 @@ __all__ = [
     "gauge",
     "histogram",
     "profile",
+    "profiled_spans",
     "reset",
     "span",
     "telemetry",

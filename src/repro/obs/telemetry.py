@@ -1,19 +1,30 @@
-"""Process-wide metrics registry: counters, gauges, histograms, spans.
+"""Process-wide metrics registry: counters, gauges, histograms, spans,
+and the compile tally.
 
 The single place every layer meters into — the solver's transfer
 counter, the plan cache's compile accounting, the serving request
 stream, the federated CommLedger totals.  Everything is host-side and
-synchronous (the request loop is), and everything is **off by default**:
-the registry only records when observability is enabled, via the
+synchronous (the request loop is), and the registry is **off by
+default**: it only records when observability is enabled, via the
 ``REPRO_OBS=1`` environment variable or :func:`enable`.
 
-Zero-overhead contract: with observability disabled, every mutation
-method returns after a single attribute check, :func:`span` returns a
-shared no-op context manager (no ``perf_counter`` call, no allocation),
-and :func:`device_fetch` degrades to a bare ``jax.device_get``.  Nothing
-here ever runs *inside* jitted code — device-side phase annotation is
-``jax.named_scope`` (:mod:`repro.obs.profile`), which costs only at
-trace time — so enabling telemetry cannot change what XLA executes.
+Spans are profiler spans: :func:`span` always enters
+``jax.profiler.TraceAnnotation("repro.<name>")``, so every profile of
+the program (``obs.profile.trace`` or any ``jax.profiler`` session)
+shows its phases on the device's clock, nested by thread.  While a
+profile is being recorded, each span is also kept in
+:func:`profiled_spans` with its host-clock ends and the compiles that
+finished inside it.
+
+Zero-overhead contract: with observability disabled and no profile
+being recorded, every mutation method returns after a single attribute
+check, :func:`span` returns the profiler's own annotation (which does
+nothing without a profile: no registry lookup, no clock read), and
+:func:`device_fetch` degrades to a bare ``jax.device_get``.  The compile
+listener runs only when JAX compiles.  Nothing here ever runs *inside*
+jitted code — device-side phase annotation is ``jax.named_scope``
+(:mod:`repro.obs.profile`), which costs only at trace time — so enabling
+telemetry cannot change what XLA executes.
 
 Metric handles are process-wide singletons keyed by (name, labels):
 ``counter("x", tenant="a")`` returns the same object on every call, so
@@ -24,9 +35,13 @@ any time without storing samples.
 from __future__ import annotations
 
 import bisect
+import collections
+import dataclasses
 import os
 import threading
 import time
+
+import jax
 
 #: Latency buckets (seconds): ~log-spaced from 100us to 30s.  Chosen to
 #: straddle the repo's real request latencies — smoke solves run ~1ms-1s,
@@ -216,57 +231,159 @@ def histogram(name: str, help: str = "",
 
 
 def reset() -> None:
-    """Clear every registered metric (test isolation; events reset
-    separately via :func:`repro.obs.events.reset`)."""
+    """Clear every registered metric and the profiled spans (test
+    isolation; events reset separately via
+    :func:`repro.obs.events.reset`).  The compile tally is never
+    reset: it counts the process's compiles."""
     REGISTRY.reset()
+    _SPAN_LOG.clear()
 
 
 # ---------------------------------------------------------------------------
 # Spans
 # ---------------------------------------------------------------------------
 
-class _NullSpan:
-    """Shared do-nothing context manager returned while disabled."""
+#: Prefix of every span's name in a profile.
+SPAN_PREFIX = "repro."
 
-    __slots__ = ()
+#: Spans kept by :func:`profiled_spans` (the oldest are dropped first).
+_SPAN_LOG_SIZE = 65536
 
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
+_ANNOTATION = jax.profiler.TraceAnnotation
 
 
-NULL_SPAN = _NullSpan()
+@dataclasses.dataclass(frozen=True)
+class SpanRecord:
+    """One span taken while a profile was being recorded.
+
+    ``start``/``end`` are ``time.perf_counter()`` seconds; ``depth`` is
+    the number of spans open around it on its thread; ``compiles`` the
+    JAX compiles (see :func:`compiles`) that finished while it was open;
+    ``labels`` its keyword stats in the profile.
+    """
+
+    name: str
+    start: float
+    end: float
+    depth: int
+    compiles: int
+    labels: dict
+
+
+_SPAN_LOG: collections.deque = collections.deque(maxlen=_SPAN_LOG_SIZE)
+_OPEN = threading.local()
 
 
 class _Span:
-    __slots__ = ("_hist", "_t0")
+    """A profiler span that also times itself: into the
+    ``repro_span_seconds`` histogram while observability is enabled, and
+    into :func:`profiled_spans` while a profile is being recorded."""
 
-    def __init__(self, hist: Histogram):
-        self._hist = hist
-        self._t0 = 0.0
+    __slots__ = ("_name", "_labels", "_ann", "_log", "_t0", "_c0",
+                 "_depth")
+
+    def __init__(self, name: str, labels: dict, log: bool):
+        self._name = name
+        self._labels = labels
+        self._log = log
+        self._ann = _ANNOTATION(SPAN_PREFIX + name, **labels)
 
     def __enter__(self):
+        self._ann.__enter__()
+        if self._log:
+            self._depth = getattr(_OPEN, "depth", 0)
+            _OPEN.depth = self._depth + 1
+            self._c0 = _COMPILES.count
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._hist.observe(time.perf_counter() - self._t0)
+        t1 = time.perf_counter()
+        if self._log:
+            _OPEN.depth = self._depth
+            n = _COMPILES.count - self._c0
+            self._ann.set_metadata(compiles=n)
+            _SPAN_LOG.append(SpanRecord(self._name, self._t0, t1,
+                                        self._depth, n, self._labels))
+        self._ann.__exit__(*exc)
+        if _STATE.enabled:
+            histogram("repro_span_seconds",
+                      help="host-side span timings by phase",
+                      span=self._name).observe(t1 - self._t0)
         return False
 
 
 def span(name: str, **labels):
-    """Context timer recording into ``repro_span_seconds{span=name}``.
+    """A span of the program's host path: ``repro.<name>`` in a profile,
+    with ``labels`` as its stats, nested in the span open around it.
 
-    Disabled mode returns the shared :data:`NULL_SPAN` singleton — no
-    clock read, no allocation, no registry lookup.
+    With observability disabled and no profile being recorded this is
+    ``jax.profiler.TraceAnnotation`` itself, the profiler's no-op: no
+    clock read, no registry lookup.  While enabled, the span's duration
+    goes to ``repro_span_seconds{span=name}``; while a profile is being
+    recorded, the span is kept in :func:`profiled_spans` and its
+    compiles are added to its stats.
     """
-    if not _STATE.enabled:
-        return NULL_SPAN
-    return _Span(histogram("repro_span_seconds",
-                           help="host-side span timings by phase",
-                           span=name, **labels))
+    log = _ANNOTATION.is_enabled()
+    if not (_STATE.enabled or log):
+        return _ANNOTATION(SPAN_PREFIX + name, **labels)
+    return _Span(name, labels, log)
+
+
+def profiled_spans() -> list[SpanRecord]:
+    """The spans taken while a profile was being recorded, oldest first.
+
+    The same spans as the profile's ``repro.*`` host events, on the
+    host clock: for a reader in this process that has the device's
+    busy intervals but not the profile's host events.
+    """
+    return list(_SPAN_LOG)
+
+
+# ---------------------------------------------------------------------------
+# The compile tally
+# ---------------------------------------------------------------------------
+
+#: JAX's event for a backend compile or a load from the persistent
+#: compilation cache (``jax._src.dispatch``).
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+class _Tally:
+    __slots__ = ("count", "seconds", "lock")
+
+    def __init__(self):
+        self.count = 0
+        self.seconds = 0.0
+        self.lock = threading.Lock()
+
+
+_COMPILES = _Tally()
+
+
+def _on_duration(event: str, seconds: float, **_) -> None:
+    if event != COMPILE_EVENT:
+        return
+    with _COMPILES.lock:
+        _COMPILES.count += 1
+        _COMPILES.seconds += seconds
+    if _STATE.enabled:
+        counter("repro_compiles_total",
+                help="JAX backend compiles and compilation-cache "
+                     "loads").inc()
+        counter("repro_compile_seconds_total",
+                help="seconds spent in JAX backend compiles and "
+                     "compilation-cache loads").inc(seconds)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def compiles() -> tuple[int, float]:
+    """(count, seconds) of the JAX compiles this process has made: each
+    backend compile or load from the persistent compilation cache,
+    counted whether or not observability is enabled."""
+    return _COMPILES.count, _COMPILES.seconds
 
 
 # ---------------------------------------------------------------------------

@@ -15,21 +15,21 @@ executable, per-session residual certificates split back out.
 :meth:`SolveService.solve` for singleton groups, and keeps every
 session/ledger side effect identical to the sequential path — warm
 state updated, cold baselines respected, plan hits and the *batch*
-executable's compile metered per session.
+executable's compile metered per session, and the group's measured
+seconds and compiles shared among its responses.
 """
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections import OrderedDict
 
 import jax
-import jax.numpy as jnp
 
 from repro.api.solver import solve_many
 from repro.engine import capped as _capped
 from repro.serving.cache import PlanKey
-from repro.serving.service import SolveResponse, SolveService
+from repro.serving.service import (SolveResponse, SolveService, _Clock,
+                                   _fetch)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -105,6 +105,7 @@ def _solve_group(service: SolveService,
     cfg = sessions[0].config
     B = len(group)
 
+    clock = _Clock()
     # per-session plan lookups meter the *batch* executable signature:
     # a vmapped executable over B problems is a different XLA trace
     # than the singleton one, shared by the whole group — the first
@@ -114,49 +115,24 @@ def _solve_group(service: SolveService,
     lookups = [service._plan(sess.problem, cfg, sig=batch_sig)
                for sess in sessions]
 
-    problems, warms = [], []
-    for sess, req in zip(sessions, group):
-        warms.append(sess.w is not None and not req.cold)
-        problems.append(sess.problem)
-
-    def warm_starts():
-        # fresh copies per run: the stacked buffers are donated on
-        # TPU/GPU, and the compile/execute split below runs twice
-        w0s, u0s = [], []
-        for sess, problem, warm in zip(sessions, problems, warms):
-            if warm:
-                w0s.append(jnp.copy(sess.w))
-                u0s.append(problem.regularizer.project_dual(
-                    jnp.copy(sess.u), problem.graph, problem.lam))
-            else:
-                w0s.append(None)
-                u0s.append(None)
-        return w0s, u0s
-
-    w0s, u0s = warm_starts()
-    t0 = time.perf_counter()
-    results = solve_many(problems, cfg, w0s=w0s, u0s=u0s)
+    problems = [sess.problem for sess in sessions]
+    warms = [sess.w is not None and not req.cold
+             for sess, req in zip(sessions, group)]
+    # the stacked buffers are donated on TPU/GPU: warm starts are copies
+    w0s, u0s = zip(*(
+        service._warm_state(sess.w, sess.u, problem) if warm
+        else (None, None)
+        for sess, problem, warm in zip(sessions, problems, warms)))
+    results = solve_many(problems, cfg, w0s=list(w0s), u0s=list(u0s))
+    facts = [_fetch(result) for result in results]
     jax.block_until_ready(results[-1].w)
-    total = time.perf_counter() - t0
-    seconds = total / B                        # amortized per session
-    solve_seconds, compile_seconds = seconds, 0.0
-    if any(compiled for _, _, compiled in lookups):
-        # the group shares one vmapped executable; re-execute it warm
-        # to split the XLA trace out of the per-session timing (as in
-        # SolveService.solve — deterministic, second result returned)
-        w0s, u0s = warm_starts()
-        t1 = time.perf_counter()
-        results = solve_many(problems, cfg, w0s=w0s, u0s=u0s)
-        jax.block_until_ready(results[-1].w)
-        exec_total = time.perf_counter() - t1
-        solve_seconds = exec_total / B
-        compile_seconds = max(total - exec_total, 0.0) / B
+    timing = clock.read()
 
     iterations = int(results[0].diagnostics.get(
         "iterations", _capped(cfg.num_iters, cfg.metric_every)))
     responses = []
-    for sess, req, result, warm, (plan, hit, compiled) in zip(
-            sessions, group, results, warms, lookups):
+    for sess, req, result, fact, warm, (plan, hit, compiled) in zip(
+            sessions, group, results, facts, warms, lookups):
         sess.w, sess.u = result.w, result.u
         sess.solves += 1
         cold_ref = sess.cold_iterations if warm else None
@@ -167,9 +143,8 @@ def _solve_group(service: SolveService,
         led.record_solve(cache_hit=hit, compiled=compiled,
                          iterations=iterations, cold_ref=cold_ref)
         responses.append(service._response(
-            sess, result, warm=warm, cache_hit=hit, compiled=compiled,
-            iterations=iterations, seconds=seconds,
-            solve_seconds=solve_seconds,
-            compile_seconds=compile_seconds if compiled else 0.0,
-            queue_wait=req.queue_wait, batch_width=B))
+            sess, result, fact, timing.share(B, first=not responses),
+            warm=warm, cache_hit=hit, compiled=compiled,
+            iterations=iterations, queue_wait=req.queue_wait,
+            batch_width=B))
     return responses

@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -105,14 +106,19 @@ class SolveResponse:
     ``certificate`` carries the eq.-11 dual-infeasibility /
     stationarity diagnostics; ``meets_sla`` is residual <= tol.
 
-    Timing is split: ``seconds`` is the wall clock of the request's
-    first run, ``solve_seconds`` the pure-execution cost (a compiled
-    request is re-executed once so the XLA trace can be attributed to
-    ``compile_seconds = seconds - solve_seconds``; for an already-warm
-    executable ``solve_seconds == seconds`` and ``compile_seconds`` is
-    0).  ``queue_wait`` counts *submissions* (not wall time) the
-    request sat behind in the serving queue; ``batch_width`` is the
-    number of sessions solved by the same batched executable.
+    Timing is measured, never re-run: ``seconds`` is the request's wall
+    clock (plan lookup, warm state, solve, certificate and the
+    response's host fetches); ``compiles`` counts the JAX compiles
+    (backend compiles and compilation-cache loads, :func:`repro.obs.compiles`)
+    that finished inside it, ``compile_seconds`` their summed seconds,
+    and ``solve_seconds = seconds - compile_seconds``.  A path sweep or
+    a batched group is answered by one program: each of its responses
+    carries an even share of its seconds and compile seconds, and its
+    first response carries its compiles.  ``compiled`` is the plan
+    cache's "new executable signature" flag, not a measurement.
+    ``queue_wait`` counts *submissions* (not wall time) the request sat
+    behind in the serving queue; ``batch_width`` is the number of
+    sessions solved by the same batched executable.
     """
 
     session_id: str
@@ -132,6 +138,49 @@ class SolveResponse:
     compile_seconds: float = 0.0
     queue_wait: int = 0
     batch_width: int = 1
+    compiles: int = 0
+
+
+class _Timing(NamedTuple):
+    """Wall seconds, compiles and compile seconds of one request."""
+
+    seconds: float
+    compiles: int
+    compile_seconds: float
+
+    def share(self, n: int, first: bool) -> "_Timing":
+        """One of the ``n`` responses a single program answered: an even
+        share of the seconds, the compiles on the first response."""
+        return _Timing(self.seconds / n, self.compiles if first else 0,
+                       self.compile_seconds / n)
+
+
+class _Clock:
+    """The wall clock and the process's compile tally from a request's
+    start; :meth:`read` gives what the request has taken so far."""
+
+    __slots__ = ("t0", "c0", "s0")
+
+    def __init__(self):
+        self.c0, self.s0 = obs.compiles()
+        self.t0 = time.perf_counter()
+
+    def read(self) -> _Timing:
+        t = time.perf_counter()
+        c, s = obs.compiles()
+        return _Timing(t - self.t0, c - self.c0, s - self.s0)
+
+
+def _fetch(result) -> tuple[float, float, dict]:
+    """A response's host facts: the last eq.-11 residual, the last
+    objective and the certificate's scalars (the request's sync)."""
+    residual = (float(result.residual[-1])
+                if result.residual is not None else float("nan"))
+    certificate = {k: float(v)
+                   for k, v in result.diagnostics.items()
+                   if k not in ("iterations", "route")
+                   and not k.startswith("halo_") and np.ndim(v) == 0}
+    return residual, float(result.objective[-1]), certificate
 
 
 class SolveService:
@@ -219,28 +268,29 @@ class SolveService:
         rows of added edges.  ``lam`` retargets the TV strength.
         """
         sess = self.session(session_id)
-        if delta is not None:
-            sess.problem = dataclasses.replace(
-                sess.problem, data=_apply_delta(sess.problem.data, delta))
-        if patch is not None:
-            old_graph = sess.problem.graph
-            new_graph = _apply_patch(old_graph, patch)
-            if sess.u is not None:
-                sess.u = jnp.asarray(transfer_edge_duals(
-                    old_graph, new_graph, np.asarray(sess.u)))
-            sess.problem = dataclasses.replace(sess.problem,
-                                               graph=new_graph)
-        if lam is not None:
-            sess.problem = sess.problem.with_lam(float(lam))
-        if patch is not None or lam is not None:
-            # the cold baseline measured a *different* problem (other
-            # structure / other lambda); the next cold-reference solve
-            # re-establishes it, so warm_iteration_ratio never mixes
-            sess.cold_iterations = None
-        sess.updates += 1
-        led = self.ledger(sess.tenant)
-        led.requests += 1
-        led.updates += 1
+        with obs.span("service_update_session", session=session_id):
+            if delta is not None:
+                sess.problem = dataclasses.replace(
+                    sess.problem, data=_apply_delta(sess.problem.data, delta))
+            if patch is not None:
+                old_graph = sess.problem.graph
+                new_graph = _apply_patch(old_graph, patch)
+                if sess.u is not None:
+                    sess.u = jnp.asarray(transfer_edge_duals(
+                        old_graph, new_graph, np.asarray(sess.u)))
+                sess.problem = dataclasses.replace(sess.problem,
+                                                   graph=new_graph)
+            if lam is not None:
+                sess.problem = sess.problem.with_lam(float(lam))
+            if patch is not None or lam is not None:
+                # the cold baseline measured a *different* problem (other
+                # structure / other lambda); the next cold-reference solve
+                # re-establishes it, so warm_iteration_ratio never mixes
+                sess.cold_iterations = None
+            sess.updates += 1
+            led = self.ledger(sess.tenant)
+            led.requests += 1
+            led.updates += 1
 
     def close(self, session_id: str) -> None:
         sess = self._sessions.pop(session_id, None)
@@ -253,18 +303,19 @@ class SolveService:
     # -- solving -------------------------------------------------------------
     def _plan(self, problem: Problem, config: SolverConfig,
               sig: tuple | None = None) -> tuple[Plan, bool, bool]:
-        key = PlanKey.for_problem(problem, config)
+        with obs.span("service_plan"):
+            key = PlanKey.for_problem(problem, config)
 
-        def build() -> Plan:
-            layout = None
-            if (config.backend == "pallas"
-                    and _should_fuse(problem, config)
-                    and problem.graph.num_edges):
-                # the layout the fusion gate sized against the VMEM cap
-                layout = _graph_layout(problem.graph)
-            return Plan(key=key, layout=layout)
+            def build() -> Plan:
+                layout = None
+                if (config.backend == "pallas"
+                        and _should_fuse(problem, config)
+                        and problem.graph.num_edges):
+                    # the layout the fusion gate sized against the VMEM cap
+                    layout = _graph_layout(problem.graph)
+                return Plan(key=key, layout=layout)
 
-        return self.plans.get_or_build(key, build, sig=sig)
+            return self.plans.get_or_build(key, build, sig=sig)
 
     def _with_plan(self, problem: Problem, plan: Plan) -> Problem:
         if plan.layout is None or problem.graph.layout is plan.layout:
@@ -285,61 +336,51 @@ class SolveService:
         wait; direct callers leave it 0).
         """
         sess = self.session(session_id)
-        cfg = sess.config
-        plan, hit, compiled = self._plan(sess.problem, cfg)
-        problem = self._with_plan(sess.problem, plan)
+        with obs.span("service_solve", session=session_id,
+                      request=sess.solves + 1):
+            clock = _Clock()
+            cfg = sess.config
+            plan, hit, compiled = self._plan(sess.problem, cfg)
+            problem = self._with_plan(sess.problem, plan)
 
-        warm = sess.w is not None and not cold
+            warm = sess.w is not None and not cold
+            w0, u0 = (self._warm_state(sess.w, sess.u, problem) if warm
+                      else (None, None))
+            result = Solver(cfg).run(problem, w0=w0, u0=u0, w_true=w_true)
 
-        def warm_state():
+            iterations = int(result.diagnostics.get(
+                "iterations", _capped(cfg.num_iters, cfg.metric_every)))
+            sess.w, sess.u = result.w, result.u
+            sess.solves += 1
+            cold_ref = sess.cold_iterations if warm else None
             if not warm:
-                return None, None
-            # copies: backends donate warm-start buffers on TPU/GPU
-            w0 = jnp.copy(sess.w)
+                # only true from-zeros solves (first solve, forced cold,
+                # post-update-reset) may define the cold baseline — a warm
+                # solve standing in as baseline would fake the ratio
+                sess.cold_iterations = iterations
+
+            led = self.ledger(sess.tenant)
+            led.requests += 1
+            led.record_solve(cache_hit=hit, compiled=compiled,
+                             iterations=iterations, cold_ref=cold_ref)
+            with obs.span("service_response"):
+                facts = _fetch(result)
+                jax.block_until_ready(result.w)
+                return self._response(sess, result, facts, clock.read(),
+                                      warm=warm, cache_hit=hit,
+                                      compiled=compiled, iterations=iterations,
+                                      queue_wait=queue_wait)
+
+    @staticmethod
+    def _warm_state(w, u, problem: Problem):
+        """Warm-start copies of a session's cached state (backends donate
+        warm-start buffers on TPU/GPU), the duals re-projected onto the
+        current lambda's feasible box."""
+        with obs.span("service_warm_state"):
+            w0 = jnp.copy(w)
             u0 = problem.regularizer.project_dual(
-                jnp.copy(sess.u), problem.graph, problem.lam)
+                jnp.copy(u), problem.graph, problem.lam)
             return w0, u0
-
-        w0, u0 = warm_state()
-        t0 = time.perf_counter()
-        result = Solver(cfg).run(problem, w0=w0, u0=u0, w_true=w_true)
-        jax.block_until_ready(result.w)
-        seconds = time.perf_counter() - t0
-        solve_seconds, compile_seconds = seconds, 0.0
-        if compiled:
-            # the executable is warm now: one re-execution isolates the
-            # pure run cost, attributing the remainder to the XLA trace
-            # (the solve is deterministic, so the re-run's result is the
-            # one returned)
-            w0, u0 = warm_state()
-            t1 = time.perf_counter()
-            result = Solver(cfg).run(problem, w0=w0, u0=u0,
-                                     w_true=w_true)
-            jax.block_until_ready(result.w)
-            solve_seconds = time.perf_counter() - t1
-            compile_seconds = max(seconds - solve_seconds, 0.0)
-
-        iterations = int(result.diagnostics.get(
-            "iterations", _capped(cfg.num_iters, cfg.metric_every)))
-        sess.w, sess.u = result.w, result.u
-        sess.solves += 1
-        cold_ref = sess.cold_iterations if warm else None
-        if not warm:
-            # only true from-zeros solves (first solve, forced cold,
-            # post-update-reset) may define the cold baseline — a warm
-            # solve standing in as baseline would fake the ratio
-            sess.cold_iterations = iterations
-
-        led = self.ledger(sess.tenant)
-        led.requests += 1
-        led.record_solve(cache_hit=hit, compiled=compiled,
-                         iterations=iterations, cold_ref=cold_ref)
-        return self._response(sess, result, warm=warm, cache_hit=hit,
-                              compiled=compiled, iterations=iterations,
-                              seconds=seconds,
-                              solve_seconds=solve_seconds,
-                              compile_seconds=compile_seconds,
-                              queue_wait=queue_wait)
 
     def solve_path(self, session_id: str, lams,
                    *, w_true=None) -> list[SolveResponse]:
@@ -349,6 +390,7 @@ class SolveService:
         be at these lambdas" without disturbing the session's warm state
         or its current lambda.
         """
+        clock = _Clock()
         sess = self.session(session_id)
         lams = np.asarray(lams, np.float32).reshape(-1)
         # fixed-length vmapped scan: tol off, residual trace on
@@ -356,23 +398,7 @@ class SolveService:
                                   continuation=False)
         plan, hit, compiled = self._plan(sess.problem, cfg)
         problem = self._with_plan(sess.problem, plan)
-
-        t0 = time.perf_counter()
         result = _solve_path(problem, lams, cfg, w_true=w_true)
-        jax.block_until_ready(result.w)
-        total = time.perf_counter() - t0
-        npts = max(len(lams), 1)
-        seconds = total / npts
-        solve_seconds, compile_seconds = seconds, 0.0
-        if compiled:
-            # as in solve(): re-execute the warm executable to split the
-            # XLA trace out of the per-point timing
-            t1 = time.perf_counter()
-            result = _solve_path(problem, lams, cfg, w_true=w_true)
-            jax.block_until_ready(result.w)
-            exec_total = time.perf_counter() - t1
-            solve_seconds = exec_total / npts
-            compile_seconds = max(total - exec_total, 0.0) / npts
 
         iters = _capped(cfg.final_iters, cfg.metric_every)
         warm_iters = _capped(cfg.warm_iters, cfg.metric_every)
@@ -381,36 +407,31 @@ class SolveService:
         led.record_path(points=len(lams), point_iterations=iters,
                         warm_iterations=warm_iters, cache_hit=hit,
                         compiled=compiled)
-        responses = []
-        for i in range(len(lams)):
-            point = jax.tree_util.tree_map(lambda a, i=i: a[i], result)
-            responses.append(self._response(
-                sess, point, warm=False, cache_hit=hit,
-                compiled=compiled if i == 0 else False, iterations=iters,
-                seconds=seconds, tol=sess.config.tol,
-                solve_seconds=solve_seconds,
-                compile_seconds=compile_seconds if i == 0 else 0.0,
-                kind="path"))
-        return responses
+        points = [jax.tree_util.tree_map(lambda a, i=i: a[i], result)
+                  for i in range(len(lams))]
+        facts = [_fetch(point) for point in points]
+        jax.block_until_ready(result.w)
+        timing = clock.read()
+        npts = max(len(lams), 1)
+        return [self._response(
+                    sess, point, fact, timing.share(npts, i == 0),
+                    warm=False, cache_hit=hit,
+                    compiled=compiled if i == 0 else False,
+                    iterations=iters, tol=sess.config.tol, kind="path")
+                for i, (point, fact) in enumerate(zip(points, facts))]
 
-    def _response(self, sess: Session, result, *, warm: bool,
-                  cache_hit: bool, compiled: bool, iterations: int,
-                  seconds: float, tol: float | None = ...,
-                  solve_seconds: float | None = None,
-                  compile_seconds: float = 0.0, queue_wait: int = 0,
+    def _response(self, sess: Session, result, facts: tuple,
+                  timing: _Timing, *, warm: bool, cache_hit: bool,
+                  compiled: bool, iterations: int,
+                  tol: float | None = ..., queue_wait: int = 0,
                   batch_width: int = 1,
                   kind: str = "solve") -> SolveResponse:
         tol = sess.config.tol if tol is ... else tol
-        residual = (float(result.residual[-1])
-                    if result.residual is not None else float("nan"))
-        certificate = {k: float(v)
-                       for k, v in result.diagnostics.items()
-                       if k not in ("iterations", "route")
-                       and not k.startswith("halo_") and np.ndim(v) == 0}
+        residual, objective, certificate = facts
         resp = SolveResponse(
             session_id=sess.session_id,
             w=result.w,
-            objective=float(result.objective[-1]),
+            objective=objective,
             residual=residual,
             certificate=certificate,
             lam=float(result.lam),
@@ -419,13 +440,13 @@ class SolveService:
             warm=warm,
             cache_hit=cache_hit,
             compiled=compiled,
-            seconds=seconds,
+            seconds=timing.seconds,
             meets_sla=bool(tol is not None and residual <= tol),
-            solve_seconds=(seconds if solve_seconds is None
-                           else solve_seconds),
-            compile_seconds=compile_seconds,
+            solve_seconds=timing.seconds - timing.compile_seconds,
+            compile_seconds=timing.compile_seconds,
             queue_wait=queue_wait,
             batch_width=batch_width,
+            compiles=timing.compiles,
         )
         if obs.enabled():
             self._record_obs(sess, resp, kind=kind)
@@ -441,12 +462,8 @@ class SolveService:
                       help="request wall clock (compile included)"
                       ).observe(resp.seconds)
         obs.histogram("repro_serving_execute_seconds",
-                      help="pure-execution solve seconds"
+                      help="request seconds outside JAX compiles"
                       ).observe(resp.solve_seconds)
-        if resp.compile_seconds:
-            obs.counter("repro_serving_compile_seconds_total",
-                        help="seconds spent in XLA traces"
-                        ).inc(resp.compile_seconds)
         obs.histogram("repro_serving_queue_wait",
                       help="submissions a request waited behind",
                       buckets=obs.COUNT_BUCKETS

@@ -170,6 +170,39 @@ def test_unfused_kernels_compile_at_million_node_scale(one_chip,
     assert compiled.as_text().count("tpu_custom_call") >= 2
 
 
+def test_kernels_carry_stable_names(one_chip, no_compile_cache):
+    """Each Pallas kernel lowers under its own name, which a device
+    trace's op and a benchmark's reduction key on: ``fused_pd_step``,
+    ``tv_prox`` and ``batched_affine``.  The fused kernel compiles to
+    an instruction of that name."""
+    import re
+
+    def s(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def step(u, bound, p, v):
+        return ops.tv_prox(u, bound, interpret=False), \
+            ops.batched_affine(p, v, interpret=False)
+
+    low = jax.jit(step).lower(s((1024, 2)), s((1024,)), s((512, 2, 2)),
+                              s((512, 2)))
+    names = re.findall(r'kernel_name = "([^"]*)"', low.as_text())
+    assert sorted(names) == ["batched_affine", "tv_prox"]
+
+    from repro.kernels.pd_step import fused_pd_step
+    g = grid_graph(np.random.default_rng(0), 16, 16)
+    lt = plan_edge_blocks(g)
+    pkeys, shapes = _prox_leaves(SquaredLoss())
+    low = jax.jit(lambda *a: fused_pd_step(
+        *a, loss=SquaredLoss(), reg=TotalVariation(), pkeys=pkeys,
+        block_nodes=lt.block_nodes, block_edges=lt.block_edges, kn=lt.kn,
+        klo=lt.klo, khi=lt.khi, rho=1.9, compute_residual=True,
+        interpret=False)).lower(*_fused_operands(lt, one_chip, shapes))
+    assert re.findall(r'kernel_name = "([^"]*)"',
+                      low.as_text()) == ["fused_pd_step"]
+    assert "%fused_pd_step" in low.compile().as_text()
+
+
 def test_certificate_pseudo_inverse_compiles_at_million_nodes(
         one_chip, no_compile_cache):
     """The eq.-11 certificate's per-node pseudo-inverse at 1M nodes
