@@ -116,15 +116,17 @@ class EdgeBlockLayout:
 # TPU VMEM holds an array in (8, 128) tiles over its two minor axes
 _SUBLANES, _LANES = 8, 128
 # live copies the v5e compiler keeps of the fused kernel's in-kernel
-# values (the bf16 incidence matrix, the f32 edge-window and node-window
-# values) and fixed scratch, fitted to the smallest vmem_limit_bytes it
-# accepts (bisected on compiles for a described v5e):
+# values (the bf16 incidence matrices, the f32 edge-window and
+# node-window values) and fixed scratch, fitted to the smallest
+# vmem_limit_bytes it accepts (bisected to 0.1 MiB on compiles for a
+# described v5e, with the incidence built in both streamed
+# orientations; the §5 SBM row, one block, holds both whole):
 #   layout (BV, EB, kn, klo, khi)   loss       accepted   estimate
-#   512x512 lattice (256,512,3,2,2) squared    19.2 MiB   36.3 MiB
-#   512x512 lattice (512,1024,2,1,1) squared   28.6 MiB   52.1 MiB
-#   512x512 lattice (256,512,3,2,2) logistic   54.2 MiB   76.8 MiB
-#   §5 SBM (304,11072,1,0,0), 10 iters squared 55.1 MiB   69.2 MiB
-#   1024^2 lattice / 4 shards (256,512,4,3,3)  17.7 MiB   58.0 MiB
+#   512x512 lattice (256,512,3,2,2) squared    15.0 MiB   36.4 MiB
+#   512x512 lattice (512,1024,2,1,1) squared   20.7 MiB   52.2 MiB
+#   512x512 lattice (256,512,3,2,2) logistic   48.9 MiB   76.9 MiB
+#   §5 SBM (304,11072,1,0,0), 10 iters squared 63.7 MiB   69.0 MiB
+#   1024^2 lattice / 4 shards (256,512,4,3,3)  19.6 MiB   58.2 MiB
 _INC_COPIES, _EDGE_COPIES = 5, 4
 _VMEM_SLACK = 2 << 20
 # per-TensorCore VMEM of a TPU v5e, the chip this repository targets
@@ -150,14 +152,14 @@ def fused_window_bytes(block_nodes: int, block_edges: int, kn: int,
                        itemsize: int = 4) -> int:
     """VMEM one grid step of the fused primal-dual kernel needs on a TPU.
 
-    Two parts.  The in-kernel values: the (EW, NW) bf16 signed incidence
-    matrix, the f32 edge-window state (a 2-wide feature axis fills a
-    128-lane row) and the f32 node-window state with the prox
-    parameters, times the live copies the compiler keeps (fitted; see
-    the table above).  And the streamed blocks, double-buffered:
-    ``itemsize`` is the *storage* dtype's width (4 for f32, 2 for bf16)
-    and scales the state and prox-parameter blocks, while the endpoint
-    and step operands stay 4-byte.
+    Two parts.  The in-kernel values: the window's bf16 signed
+    incidence, counted as one (EW, NW) matrix, the f32 edge-window state
+    (a 2-wide feature axis fills a 128-lane row) and the f32 node-window
+    state with the prox parameters, times the live copies the compiler
+    keeps (fitted; see the table above).  And the streamed blocks,
+    double-buffered: ``itemsize`` is the *storage* dtype's width (4 for
+    f32, 2 for bf16) and scales the state and prox-parameter blocks,
+    while the endpoint and step operands stay 4-byte.
 
     ``param_floats`` is the per-node word count of the loss's prox
     parameters and in-kernel temporaries, tiled

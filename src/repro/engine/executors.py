@@ -61,19 +61,33 @@ def _bf16_parts(x: jnp.ndarray) -> tuple:
     return tuple(parts)
 
 
-def _exact_dot(a: jnp.ndarray, x: jnp.ndarray, contract: tuple):
+def _signed_one_hot(src: jnp.ndarray, dst: jnp.ndarray, shape: tuple,
+                    axis: int) -> jnp.ndarray:
+    """bf16 ``shape`` matrix, +1 where the iota along ``axis`` equals
+    ``src`` and -1 where it equals ``dst`` (both broadcast to ``shape``);
+    bf16 holds 0/+-1 exactly."""
+    idx = jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+    f32 = jnp.float32
+    return ((idx == src).astype(f32) - (idx == dst).astype(f32)
+            ).astype(jnp.bfloat16)
+
+
+def _exact_dot(a: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     """f32-exact contraction of a bf16-exact matrix ``a`` (entries 0/+-1)
-    with an f32 ``x``: three single-pass bf16 MXU products, f32
-    accumulation.  The TPU's default f32 matmul rounds ``x`` to bf16,
-    and ``Precision.HIGHEST`` spends six passes (and a far longer Mosaic
-    compile) on what three exact ones give."""
-    dims = (contract, ((), ()))
-    out = None
-    for part in _bf16_parts(x):
-        y = jax.lax.dot_general(a, part, dims,
-                                preferred_element_type=jnp.float32)
-        out = y if out is None else out + y
-    return out
+    with an (rows, k) f32 ``x``: its three bf16 parts stacked on the lane
+    axis into one (rows, 3k) right-hand side, so one single-pass bf16 MXU
+    product with f32 accumulation gives all three partial products; they
+    are summed part by part.  Each output column accumulates on its own,
+    so the stacked product is bitwise three separate ones, at
+    ceil(3k/128) MXU column tiles instead of 3 * ceil(k/128).  The TPU's
+    default f32 matmul rounds ``x`` to bf16, and ``Precision.HIGHEST``
+    spends six passes (and a far longer Mosaic compile) on what one
+    exact stacked pass gives."""
+    k = x.shape[1]
+    y = jax.lax.dot_general(a, jnp.concatenate(_bf16_parts(x), axis=1),
+                            (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    return y[:, :k] + y[:, k:2 * k] + y[:, 2 * k:]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,14 +111,17 @@ class WindowExecutor:
 
     Inside a compiled TPU kernel (``mxu=True``) Mosaic refuses row
     gathers and scatters by an index array, so both products there run
-    as contractions with the window's signed incidence matrix (EW, NW)
-    (+1 at the src column, -1 at the dst column), built once per window
-    from ``ends`` with iota compares and held in bf16, which represents
-    its 0/+-1 entries exactly; the products run on the MXU at f32
-    precision (:func:`_exact_dot`).  Everywhere else (the jnp reference
-    and interpret mode, which run the same step) they are the O(EW)
-    segment sum and row gather: the contraction costs O(EW * NW), which
-    a CPU pays in full.
+    as contractions with the window's signed incidence matrix (+1 at the
+    src node, -1 at the dst node), built once per window from ``ends``
+    with iota compares and held in bf16, which represents its 0/+-1
+    entries exactly.  It is built in the orientation each product
+    streams, so neither needs an in-kernel transpose of it: (NW, EW) for
+    D^T u (from the transposed (2, EW) endpoint ids) and the owned
+    (EB, NW) rows for D z.  Each product is one stacked bf16 MXU pass
+    at f32 precision (:func:`_exact_dot`).  Everywhere else (the jnp
+    reference and interpret mode, which run the same step) they are the
+    O(EW) segment sum and row gather: the contraction costs O(EW * NW),
+    which a CPU pays in full.
 
     Precision policy: the window adapter (``kernels.ref.pd_window_step``)
     upcasts a reduced-storage (bf16) window to f32 *before* calling the
@@ -117,7 +134,8 @@ class WindowExecutor:
     weights: jnp.ndarray        # (EB, 1) lam * A_e per owned edge
     klo: int
     block_edges: int
-    incidence: jnp.ndarray | None = None    # (EW, NW) bf16 when mxu
+    incidence_t: jnp.ndarray | None = None      # (NW, EW) bf16, mxu
+    owned_incidence: jnp.ndarray | None = None  # (EB, NW) bf16, mxu
 
     @classmethod
     def from_endpoints(cls, ends: jnp.ndarray, num_nodes: int,
@@ -125,34 +143,36 @@ class WindowExecutor:
                        block_edges: int, mxu: bool = False
                        ) -> "WindowExecutor":
         """Build the executor from (EW, 2) window-relative (src, dst)
-        node ids of the window's edges; ``mxu`` builds the incidence
-        matrix the contractions use."""
-        incidence = None
+        node ids of the window's edges; ``mxu`` builds the two incidence
+        matrices the contractions use."""
+        incidence_t = owned_incidence = None
         if mxu:
-            col = jax.lax.broadcasted_iota(
-                jnp.int32, (ends.shape[0], num_nodes), 1)
-            f32 = jnp.float32
-            incidence = ((col == ends[:, 0:1]).astype(f32)
-                         - (col == ends[:, 1:2]).astype(f32)
-                         ).astype(jnp.bfloat16)
+            ends_t = ends.T                 # (2, EW): edge ids on lanes
+            owned = jax.lax.slice_in_dim(ends, klo * block_edges,
+                                         (klo + 1) * block_edges)
+            incidence_t = _signed_one_hot(ends_t[0:1], ends_t[1:2],
+                                          (num_nodes, ends.shape[0]), 0)
+            owned_incidence = _signed_one_hot(
+                owned[:, 0:1], owned[:, 1:2], (block_edges, num_nodes), 1)
         return cls(ends=ends, num_nodes=num_nodes, weights=weights,
-                   klo=klo, block_edges=block_edges, incidence=incidence)
+                   klo=klo, block_edges=block_edges,
+                   incidence_t=incidence_t,
+                   owned_incidence=owned_incidence)
 
     def _owned_rows(self, a: jnp.ndarray) -> jnp.ndarray:
         eb = self.block_edges
         return jax.lax.slice_in_dim(a, self.klo * eb, (self.klo + 1) * eb)
 
     def gather_duals(self, u: jnp.ndarray) -> jnp.ndarray:
-        if self.incidence is not None:
-            return _exact_dot(self.incidence, u, ((0,), (0,)))
+        if self.incidence_t is not None:
+            return _exact_dot(self.incidence_t, u)
         nw = self.num_nodes
         return (jax.ops.segment_sum(u, self.ends[:, 0], nw)
                 - jax.ops.segment_sum(u, self.ends[:, 1], nw))
 
     def edge_diff(self, z: jnp.ndarray) -> jnp.ndarray:
-        if self.incidence is not None:
-            return _exact_dot(self._owned_rows(self.incidence), z,
-                              ((1,), (0,)))
+        if self.owned_incidence is not None:
+            return _exact_dot(self.owned_incidence, z)
         ends = self._owned_rows(self.ends)
         return z[ends[:, 0]] - z[ends[:, 1]]
 

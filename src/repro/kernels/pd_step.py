@@ -24,10 +24,12 @@ iteration math is therefore stated once in the engine; this kernel is
 locked to it by the interpret-mode bit-parity tests.  Compiled for a
 TPU, D and D^T run as MXU contractions with the window's signed
 incidence matrix, built from the window edges' endpoints by iota
-compares (``engine.executors.WindowExecutor``): Mosaic refuses row
-gathers by an index array, and the window is small enough to hold the
-matrix.  In interpret mode they are the reference's segment sum and
-row gather.
+compares in the orientation each contraction streams
+(``engine.executors.WindowExecutor``): Mosaic refuses row gathers by an
+index array, and the window is small enough to hold the matrix.  Each
+product is one bf16 MXU pass over the three bf16 parts of its f32
+operand stacked on the lane axis, exact to f32.  In interpret mode they
+are the reference's segment sum and row gather.
 
 Layout contract (all index maps are plain ``i + j`` offsets because the
 layout pass aligns every block's halo window to exactly ``i * BV`` /

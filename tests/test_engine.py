@@ -27,6 +27,7 @@ from repro.core.mesh import make_host_mesh
 from repro.data.synthetic import make_sbm_regression
 from repro.engine import DenseExecutor, MailboxExecutor, WindowExecutor
 from repro.engine import pd_residual, pd_step
+from repro.engine.executors import _bf16_parts, _exact_dot
 from repro.kernels import ops
 from repro.scenarios import get_scenario
 
@@ -88,34 +89,122 @@ def test_pallas_kernel_is_bitwise_the_engine_step(rho):
     assert w_same >= 0.5 and u_same >= 0.5, (w_same, u_same)
 
 
-@pytest.mark.parametrize("bv", [16, 512])
-def test_window_executor_contraction_form_matches_gather_form(bv):
+def _f32_bound(a64: np.ndarray, x64: np.ndarray) -> np.ndarray:
+    """Worst-case f32 rounding of the product ``a64 @ x64`` summed in
+    f32: (terms + 2) units of f32 roundoff over the sum of |terms|."""
+    terms = np.abs(a64) @ (np.abs(x64) > 0)
+    return (terms + 2) * 2.0 ** -24 * (np.abs(a64) @ np.abs(x64))
+
+
+def _spread(rng, shape, spread: bool) -> np.ndarray:
+    """Standard normal values, or values whose magnitudes span about
+    2^-10 to 2^10, so that all three bf16 parts of most are non-zero."""
+    x = rng.standard_normal(shape)
+    return x * 2.0 ** rng.uniform(-10, 10, shape) if spread else x
+
+
+@pytest.mark.parametrize("bv,n,spread", [
+    (16, 3, False), (512, 3, False),
+    (16, 1, True), (16, 2, True), (16, 43, True), (512, 2, True)])
+def test_window_executor_contraction_form_matches_gather_form(bv, n,
+                                                               spread):
     """The incidence contractions a compiled TPU kernel runs
     (``mxu=True``) compute the same D^T u and D z, per window, as the
     segment-sum / row-gather form of the reference and interpret mode,
-    to f32 rounding; ``bv=16`` gives multi-block windows with halos."""
+    to f32 rounding; ``bv=16`` gives multi-block windows with halos.
+    The MXU form also matches a float64 product to f32 rounding, for
+    widths whose three stacked bf16 parts fill part of one lane tile
+    (n = 1, 2, 3) or cross into a second (n = 43: 3n = 129), and for
+    values whose magnitudes span 2^+-10."""
     rng = np.random.default_rng(11)
     g, _ = sbm_graph(rng, (60, 60), p_in=0.3, p_out=0.03)
     lt = plan_edge_blocks(g, block_nodes=bv)
     BV, EB = lt.block_nodes, lt.block_edges
     nw, ew = lt.kn * BV, (lt.klo + 1 + lt.khi) * EB
     ends = edge_ends_store(lt.src, lt.dst, lt.klo, lt.khi, EB)
-    u = jnp.asarray(rng.standard_normal((ends.shape[0], 3)), jnp.float32)
+    u = jnp.asarray(_spread(rng, (ends.shape[0], n), spread), jnp.float32)
     u = jnp.where((ends[:, 0] == ends[:, 1])[:, None], 0.0, u)  # pad: 0
-    z = jnp.asarray(rng.standard_normal((nw, 3)), jnp.float32)
+    z = jnp.asarray(_spread(rng, (nw, n), spread), jnp.float32)
     la = jnp.ones((EB, 1), jnp.float32)
+    lo = lt.klo * EB
     for b in range(lt.num_blocks):
         win = ends[b * EB:b * EB + ew] - b * BV
         forms = [WindowExecutor.from_endpoints(
             win, nw, la, klo=lt.klo, block_edges=EB, mxu=mxu)
             for mxu in (False, True)]
         u_win = u[b * EB:b * EB + ew]
-        np.testing.assert_allclose(forms[1].gather_duals(u_win),
-                                   forms[0].gather_duals(u_win),
-                                   rtol=1e-6, atol=1e-6)
-        np.testing.assert_allclose(forms[1].edge_diff(z),
-                                   forms[0].edge_diff(z),
-                                   rtol=1e-6, atol=1e-6)
+        if not spread:
+            np.testing.assert_allclose(forms[1].gather_duals(u_win),
+                                       forms[0].gather_duals(u_win),
+                                       rtol=1e-6, atol=1e-6)
+            np.testing.assert_allclose(forms[1].edge_diff(z),
+                                       forms[0].edge_diff(z),
+                                       rtol=1e-6, atol=1e-6)
+        # the signed incidence in float64, endpoints outside the window
+        # dropped; with magnitudes from 2^-10 to 2^10 both forms cancel,
+        # so each is held to f32 rounding of the float64 product
+        e = np.asarray(win)
+        inc = np.zeros((ew, nw))
+        for j, sign in ((0, 1.0), (1, -1.0)):
+            ok = (e[:, j] >= 0) & (e[:, j] < nw)
+            inc[np.flatnonzero(ok), e[ok, j]] += sign
+        for form in forms:
+            for got, a64, x in (
+                    (form.gather_duals(u_win), inc.T, u_win),
+                    (form.edge_diff(z), inc[lo:lo + EB], z)):
+                x64 = np.asarray(x, np.float64)
+                err = np.abs(np.asarray(got, np.float64) - a64 @ x64)
+                assert np.all(err <= _f32_bound(a64, x64)), float(err.max())
+
+
+def _three_pass_dot(a: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
+    """The f32-exact product as three separate bf16 MXU products, one per
+    bf16 part of ``x``, summed in order: the oracle for the stacked
+    pass."""
+    import jax
+    out = None
+    for part in _bf16_parts(x):
+        y = jax.lax.dot_general(a, part, (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        out = y if out is None else out + y
+    return out
+
+
+@pytest.mark.parametrize("n", [1, 2, 43])
+@pytest.mark.parametrize("product", ["gather_duals", "edge_diff"])
+def test_exact_dot_stacked_pass_is_bitwise_the_three_pass_form(product, n):
+    """``_exact_dot`` on the window's 0/+-1 bf16 incidence matrices — the
+    (NW, EW) one that D^T u contracts and the owned (EB, NW) rows that
+    D z contracts — matches a float64 product to f32 rounding and is
+    bitwise the three-product form it replaces: each output column
+    accumulates on its own, and the three partial products are summed in
+    the same order."""
+    rng = np.random.default_rng(n)
+    nw, eb, klo = 256, 256, 1
+    ew = 3 * eb
+    ends = rng.integers(-8, nw + 8, (ew, 2)).astype(np.int32)
+    ends[::7, 1] = ends[::7, 0]                  # padding rows: src == dst
+    ex = WindowExecutor.from_endpoints(
+        jnp.asarray(ends), nw, jnp.ones((eb, 1), jnp.float32), klo=klo,
+        block_edges=eb, mxu=True)
+    if product == "gather_duals":
+        a, x = ex.incidence_t, _spread(rng, (ew, n), True)
+    else:
+        a, x = ex.owned_incidence, _spread(rng, (nw, n), True)
+    x = jnp.asarray(x, jnp.float32)
+    got = _exact_dot(a, x)
+    a64, x64 = np.asarray(a, np.float64), np.asarray(x, np.float64)
+    assert set(np.unique(a64)) <= {-1.0, 0.0, 1.0}
+    err = np.abs(np.asarray(got, np.float64) - a64 @ x64)
+    assert np.all(err <= _f32_bound(a64, x64)), float(err.max())
+    if n > 1:
+        # XLA:CPU runs a one-column product as a matrix-vector product,
+        # which sums in another order than its matrix-matrix product;
+        # there the f32 bound above is the check
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(_three_pass_dot(a, x)))
+    np.testing.assert_array_equal(np.asarray(getattr(ex, product)(x)),
+                                  np.asarray(got))
 
 
 def test_mailbox_executor_equals_dense_executor_when_synced():
